@@ -39,6 +39,7 @@ import time
 from benchmarks import (bench_attention, bench_kv_cache, bench_flops,
                         bench_topk, bench_pretrain, bench_niah,
                         bench_serving, bench_ring, bench_memory)
+from repro.launch.compile_cache import use_compile_cache
 
 SUITES = {
     "attention": bench_attention,
@@ -105,6 +106,7 @@ def main() -> None:
                     help="skip appending to BENCH_<suite>.json")
     args = ap.parse_args()
 
+    use_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     for name, mod in SUITES.items():

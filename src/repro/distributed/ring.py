@@ -59,7 +59,6 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import current_mesh
@@ -335,10 +334,10 @@ def _ring_sfa_fwd(qv, qi, kv, ki, v, d, scale, axis_name, interpret,
                              interpret=interpret, block_q=block_q,
                              block_k=block_k)
     spec = _seq_spec(3, axis_name)
-    o, lse = shard_map(body, mesh=mesh,
+    o, lse = jax.shard_map(body, mesh=mesh,
                        in_specs=(spec,) * 5,
                        out_specs=(spec, _seq_spec(2, axis_name)),
-                       check_rep=False)(qv, qi, kv, ki, v)
+                       check_vma=False)(qv, qi, kv, ki, v)
     return o.astype(v.dtype), (qv, qi, kv, ki, v, o, lse)
 
 
@@ -351,11 +350,11 @@ def _ring_sfa_bwd(d, scale, axis_name, interpret, block_q, block_k, res, g):
                              block_k=block_k)
     spec3 = _seq_spec(3, axis_name)
     spec2 = _seq_spec(2, axis_name)
-    dqc, dkc, dv = shard_map(
+    dqc, dkc, dv = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec3,) * 6 + (spec2, spec3),
         out_specs=(spec3, spec3, spec3),
-        check_rep=False)(qv, qi, kv, ki, v, o, lse, g)
+        check_vma=False)(qv, qi, kv, ki, v, o, lse, g)
     zero_i = lambda a: np.zeros(a.shape, jax.dtypes.float0)
     return (dqc.astype(qv.dtype), zero_i(qi), dkc.astype(kv.dtype),
             zero_i(ki), dv.astype(v.dtype))
@@ -413,10 +412,10 @@ def _ring_op_fwd(q, k, v, sfa_k, d, scale, axis_name, interpret, blocks):
         return o, lse, qv, qi, kv, ki
 
     spec3 = _seq_spec(3, axis_name)
-    o, lse, qv, qi, kv, ki = shard_map(
+    o, lse, qv, qi, kv, ki = jax.shard_map(
         body, mesh=mesh, in_specs=(spec3,) * 3,
         out_specs=(spec3, _seq_spec(2, axis_name)) + (spec3,) * 4,
-        check_rep=False)(q, k, v)
+        check_vma=False)(q, k, v)
     return o.astype(v.dtype), (qv, qi, kv, ki, v, o, lse)
 
 
@@ -439,9 +438,9 @@ def _ring_op_bwd(sfa_k, d, scale, axis_name, interpret, blocks, res, g):
 
     spec3 = _seq_spec(3, axis_name)
     spec2 = _seq_spec(2, axis_name)
-    dq, dk, dv = shard_map(
+    dq, dk, dv = jax.shard_map(
         body, mesh=mesh, in_specs=(spec3,) * 6 + (spec2, spec3),
-        out_specs=(spec3,) * 3, check_rep=False)(qv, qi, kv, ki, v, o, lse, g)
+        out_specs=(spec3,) * 3, check_vma=False)(qv, qi, kv, ki, v, o, lse, g)
     dt = v.dtype
     return dq.astype(dt), dk.astype(dt), dv.astype(dt)
 

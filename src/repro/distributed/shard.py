@@ -28,8 +28,7 @@ model code runs single-device tests and TP meshes unchanged.
 from __future__ import annotations
 
 import jax
-from jax.experimental.shard_map import shard_map
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import current_mesh
 
@@ -42,25 +41,6 @@ def tp_degree(axis_name: str = "model") -> int:
     """Size of the TP mesh axis under the active rules context (1 if none)."""
     mesh = current_mesh()
     return 1 if mesh is None else mesh.shape.get(axis_name, 1)
-
-
-def replicate(x):
-    """Reshard ``x`` to fully-replicated under the active mesh (no-op
-    outside a mesh context).
-
-    Needed wherever a ``check_rep=False`` shard_map output meets a
-    replicated array in a shape-joining op (e.g. ``jnp.concatenate`` along
-    the sharded dim): the partitioner treats the output as device-varying
-    over the *unmentioned* mesh axes and mis-merges the replicas — on a
-    (data, model) mesh the joined values come back scaled by the data
-    degree. Pinning the shard_map side to an explicitly replicated layout
-    first restores exact semantics. Use on weight-gradient-sized arrays
-    only; replicating activation-sized shard_map outputs would all-gather
-    away the point of TP."""
-    mesh = current_mesh()
-    if mesh is None:
-        return x
-    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P()))
 
 
 def _spec(ax, ndim):
@@ -104,8 +84,8 @@ def run_tp(fn, args, in_axes, out_axes, *, reduce_out=(),
     shapes_t = (shapes,) if single else tuple(shapes)
     out_specs = tuple(_spec(ax, len(s.shape))
                       for s, ax in zip(shapes_t, out_axes_t))
-    out = shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    check_rep=False)(*args)
+    out = jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                    check_vma=False)(*args)
     return out[0] if single else out
 
 

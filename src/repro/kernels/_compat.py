@@ -1,7 +1,4 @@
-"""jax version compatibility + shared runtime switches for the Pallas kernels.
-
-``pltpu.TPUCompilerParams`` was renamed to ``pltpu.CompilerParams`` upstream;
-this repo supports both (CI pins jax 0.4.x, TPU images track newer releases).
+"""Shared runtime switch for the Pallas kernels.
 
 ``resolve_interpret`` is the one switch behind every kernel's ``interpret``
 default: kernels declare ``interpret: bool | None = None`` and resolve it
@@ -16,10 +13,6 @@ from __future__ import annotations
 import os
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
-
-CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
 
 INTERPRET_ENV = "REPRO_PALLAS_INTERPRET"
 

@@ -41,7 +41,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams, resolve_interpret
+from repro.kernels._compat import resolve_interpret
 from repro.kernels.flash_sfa import _densify_block
 
 
@@ -110,7 +110,7 @@ def code_grad_dx(vals, idx, w, *, d: int, block_n: int = 128,
         out_specs=pl.BlockSpec((block_n, block_m), lambda i, j, h: (i, j)),
         out_shape=jax.ShapeDtypeStruct((np_, mp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_n, block_m), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=resolve_interpret(interpret),
     )(vals, idx, w)
@@ -170,7 +170,7 @@ def code_grad_dw(x, vals, idx, *, d: int, block_n: int = 128,
         out_specs=pl.BlockSpec((1, block_m, d), lambda h, j, i: (h, j, 0)),
         out_shape=jax.ShapeDtypeStruct((nh, mp, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_m, d), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=resolve_interpret(interpret),
     )(x, vals, idx)

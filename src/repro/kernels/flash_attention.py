@@ -13,7 +13,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams, resolve_interpret
+from repro.kernels._compat import resolve_interpret
+from repro.kernels.flash_sfa import _finalize_tile
 
 NEG_INF = -1e30
 LANES = 128
@@ -66,11 +67,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
 
     @pl.when(kb == nkb - 1)
     def _finalize():
-        l = l_ref[:, 0]
-        o_ref[0, ...] = (acc_ref[...] /
-                         jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
-        if emit_lse:
-            lse_ref[0, :] = m_ref[:, 0] + jnp.log(jnp.maximum(l, 1e-30))
+        _finalize_tile(o_ref, lse_ref, m_ref, l_ref, acc_ref)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -96,9 +93,9 @@ def _flash_fwd(q, k, v, *, causal: bool = True, scale: float | None = None,
     out_shape = jax.ShapeDtypeStruct((bh, nq + pad_q, dv), v.dtype)
     if return_residuals:
         out_specs = [out_specs,
-                     pl.BlockSpec((1, block_q), lambda b, i, j: (b, i))]
+                     pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i))]
         out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((bh, nq + pad_q), jnp.float32)]
+                     jax.ShapeDtypeStruct((bh, 1, nq + pad_q), jnp.float32)]
     out = pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, nk_real=nk,
@@ -116,13 +113,13 @@ def _flash_fwd(q, k, v, *, causal: bool = True, scale: float | None = None,
             pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, dv), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
     if return_residuals:
         o, lse = out
-        return o[:, :nq], lse[:, :nq]
+        return o[:, :nq], lse[:, 0, :nq]
     return out[:, :nq]
 
 

@@ -43,10 +43,30 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams, resolve_interpret
+from repro.kernels._compat import resolve_interpret
 
 NEG_INF = -1e30
 LANES = 128
+# Scalar-prefetch budget for the block-skip maps: half of a v5e core's
+# 1 MiB SMEM, leaving the rest to the compiler's own scalars.
+SMEM_MAP_BYTES = 512 * 1024
+
+
+def lanes_to_row(x: jax.Array) -> jax.Array:
+    """(b, LANES) per-row statistic broadcast over lanes -> (1, b) row.
+
+    Per-row statistics (lse, delta, positions) travel through HBM as
+    ``(bh, 1, n)`` arrays: a ``(1, block)`` block of that layout satisfies
+    Mosaic's (8, 128) tiling rule, where a ``(1, block)`` block of a
+    ``(bh, n)`` array does not. The relayout is one XLU transpose per tile.
+    """
+    return x.T[:1, :]
+
+
+def row_to_column(row: jax.Array) -> jax.Array:
+    """(1, b) row -> (b, 1) column: the inverse of ``lanes_to_row``."""
+    b = row.shape[-1]
+    return jnp.broadcast_to(row, (LANES, b)).T[:, :1]
 
 
 def _densify_block(vals: jax.Array, idx: jax.Array, d: int) -> jax.Array:
@@ -106,7 +126,8 @@ def _finalize_tile(o_ref, lse_ref, m_ref, l_ref, acc_ref):
         # with l=0 -> lse ~ NEG_INF. The wrapper slices them off before
         # returning, so the backward never consumes a padded-row lse
         # (asserted in tests/test_kernels.py).
-        lse_ref[0, :] = m_ref[:, 0] + jnp.log(jnp.maximum(l, 1e-30))
+        lse = m_ref[...] + jnp.log(jnp.maximum(l_ref[...], 1e-30))
+        lse_ref[0] = lanes_to_row(lse)
 
 
 def _flash_sfa_kernel(qv_ref, qi_ref, kv_ref, ki_ref, v_ref, o_ref,
@@ -167,7 +188,7 @@ def _flash_sfa_skip_kernel(lvl_ref, ft_ref, qv_ref, qi_ref, kv_ref, ki_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    lvl = lvl_ref[b, qb, kb]
+    lvl = lvl_ref[(b * pl.num_programs(1) + qb) * nkb + kb]
 
     @pl.when(lvl == 2)
     def _compute():
@@ -188,7 +209,7 @@ def _flash_sfa_skip_kernel(lvl_ref, ft_ref, qv_ref, qi_ref, kv_ref, ki_ref,
         corr = jnp.exp(m_prev - m_new)
         e = jnp.exp(0.0 - m_new)
         acc_ref[...] = (acc_ref[...] * corr[:, None] +
-                        e[:, None] * vsum_ref[0, 0][None, :])
+                        e[:, None] * vsum_ref[0, 0])
         m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
         l_ref[...] = jnp.broadcast_to(
             (l_prev * corr + block_k * e)[:, None], l_ref.shape)
@@ -325,10 +346,10 @@ def flash_sfa(q_vals, q_idx, k_vals, k_idx, v, *, d: int, causal: bool = True,
     out_specs = pl.BlockSpec((1, block_q, dv), lambda b, q, k, *_: (b, q, 0))
     out_shape = jax.ShapeDtypeStruct((bh, nq + pad_q, dv), v.dtype)
     if return_residuals:
-        out_specs = [out_specs,
-                     pl.BlockSpec((1, block_q), lambda b, q, k, *_: (b, q))]
+        out_specs = [out_specs, pl.BlockSpec((1, 1, block_q),
+                                             lambda b, q, k, *_: (b, 0, q))]
         out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((bh, nq + pad_q), jnp.float32)]
+                     jax.ShapeDtypeStruct((bh, 1, nq + pad_q), jnp.float32)]
     scratch_shapes = [
         pltpu.VMEM((block_q, LANES), jnp.float32),
         pltpu.VMEM((block_q, LANES), jnp.float32),
@@ -353,7 +374,7 @@ def flash_sfa(q_vals, q_idx, k_vals, k_idx, v, *, d: int, causal: bool = True,
             out_specs=out_specs,
             out_shape=out_shape,
             scratch_shapes=scratch_shapes,
-            compiler_params=CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
         )(q_vals, q_idx, k_vals, k_idx, v)
@@ -362,11 +383,22 @@ def flash_sfa(q_vals, q_idx, k_vals, k_idx, v, *, d: int, causal: bool = True,
                                    causal=causal, block_q=block_q,
                                    block_k=block_k, nq_real=nq, nk_real=nk)
         vsum = v.astype(jnp.float32).reshape(
-            bh, grid[2], block_k, dv).sum(axis=2)          # (bh, nkb, dv)
+            bh, grid[2], block_k, dv).sum(axis=2, keepdims=True)  # (bh, nkb, 1, dv)
+
+        # SMEM pads the minor dim of a 2-D+ array to 128 words, so the maps
+        # travel flat: (bh, nqb, nkb) would cost 128/nkb x their size.
+        map_bytes = 2 * level.size * 4
+        if not interpret and map_bytes > SMEM_MAP_BYTES:
+            raise ValueError(
+                f"block_skip: the level/fetch maps need {map_bytes} bytes of "
+                f"SMEM at bh={bh}, {grid[1]}x{grid[2]} tiles; the budget is "
+                f"{SMEM_MAP_BYTES}. Call with block_skip=False, or split the "
+                f"batch*heads axis")
+        level, fetch = level.reshape(-1), fetch.reshape(-1)
 
         def _kv_map(b, q, k, lvl, ft):
             del lvl
-            return (b, ft[b, q, k], 0)
+            return (b, ft[(b * grid[1] + q) * grid[2] + k], 0)
 
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -379,7 +411,8 @@ def flash_sfa(q_vals, q_idx, k_vals, k_idx, v, *, d: int, causal: bool = True,
                 pl.BlockSpec((1, block_k, k_vals.shape[-1]), _kv_map),
                 pl.BlockSpec((1, block_k, k_idx.shape[-1]), _kv_map),
                 pl.BlockSpec((1, block_k, dv), _kv_map),
-                pl.BlockSpec((1, 1, dv), lambda b, q, k, *_: (b, k, 0)),
+                pl.BlockSpec((1, 1, 1, dv),
+                             lambda b, q, k, *_: (b, k, 0, 0)),
             ],
             out_specs=out_specs,
             scratch_shapes=scratch_shapes,
@@ -391,11 +424,11 @@ def flash_sfa(q_vals, q_idx, k_vals, k_idx, v, *, d: int, causal: bool = True,
                               emit_lse=return_residuals),
             grid_spec=grid_spec,
             out_shape=out_shape,
-            compiler_params=CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
         )(level, fetch, q_vals, q_idx, k_vals, k_idx, v, vsum)
     if return_residuals:
         o, lse = out
-        return o[:, :nq], lse[:, :nq]
+        return o[:, :nq], lse[:, 0, :nq]
     return out[:, :nq]

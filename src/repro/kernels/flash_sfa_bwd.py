@@ -59,8 +59,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams, resolve_interpret
-from repro.kernels.flash_sfa import _densify_block
+from repro.kernels._compat import resolve_interpret
+from repro.kernels.flash_sfa import _densify_block, row_to_column
 
 NEG_INF = -1e30
 
@@ -132,18 +132,21 @@ def _pair_closure_gather(acc: jax.Array, idx: jax.Array,
 
 def _tile_p_ds(qd, kd, do, vb, lse, delta, *, scale, rows, cols, nk_real,
                causal):
-    """Shared backward tile math: normalized P and dS for one (bq, bk) tile."""
+    """Shared backward tile math: normalized P and dS for one (bq, bk) tile.
+
+    ``lse``/``delta`` arrive as (1, bq) rows (their HBM layout) and are
+    turned into (bq, 1) columns here."""
     s = jax.lax.dot_general(qd, kd, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     ok = cols < nk_real
     if causal:
         ok &= cols <= rows
     s = jnp.where(ok, s, NEG_INF)
-    p = jnp.exp(s - lse[:, None])
+    p = jnp.exp(s - row_to_column(lse))
     p = jnp.where(ok, p, 0.0)
     dp = jax.lax.dot_general(do, vb, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)   # (bq, bk)
-    ds = p * (dp - delta[:, None]) * scale
+    ds = p * (dp - row_to_column(delta)) * scale
     return p, ds
 
 
@@ -284,21 +287,25 @@ def _bwd_impl(q_ops, k_ops, v, o, lse, g, *, d, causal, scale, block_q,
 
     def specs(qmap, kmap):
         """Input BlockSpecs in kernel order for the given q/k index maps."""
+        def row_map(*a):
+            b, i, _ = qmap(*a)
+            return b, 0, i
         return ([pl.BlockSpec((1, block_q, x.shape[-1]), qmap)
                  for x in q_ops] +
                 [pl.BlockSpec((1, block_k, x.shape[-1]), kmap)
                  for x in k_ops] +
                 [pl.BlockSpec((1, block_k, dv_dim), kmap),      # v
                  pl.BlockSpec((1, block_q, dv_dim), qmap),      # do
-                 pl.BlockSpec((1, block_q), lambda *a: qmap(*a)[:2]),  # lse
-                 pl.BlockSpec((1, block_q), lambda *a: qmap(*a)[:2])])  # delta
+                 pl.BlockSpec((1, 1, block_q), row_map),          # lse
+                 pl.BlockSpec((1, 1, block_q), row_map)])         # delta
 
     kw = dict(d=d, scale=scale, causal=causal, block_q=block_q,
               block_k=block_k, nk_real=nk, sparse=sparse, emit=emit,
               rot_dim=d if rot_dim is None else rot_dim)
-    cparams = CompilerParams(
+    cparams = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
-    operands = (*q_ops, *k_ops, v, g, lse, delta)
+    # per-row statistics as (bh, 1, n) rows: see flash_sfa.lanes_to_row
+    operands = (*q_ops, *k_ops, v, g, lse[:, None, :], delta[:, None, :])
     # compact emits shrink the dQ/dK output rows from d to the code width
     # (k for "compact", 2k for the pair-closure "compact2")
     code_w = {"compact": 1, "compact2": 2}.get(emit)

@@ -45,20 +45,57 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.sparse import SparseCode, to_feature_major
-from repro.kernels._compat import CompilerParams, resolve_interpret
+from repro.kernels._compat import resolve_interpret
+from repro.kernels.flash_sfa import _densify_block
 
 NEG_INF = -1e30
 LANES = 128
 
+# Every per-query operand (q rows, q codes, outputs) travels as a
+# (rows, 1, width) array with (1, 1, width) blocks: a (1, width) block of a
+# (rows, width) array breaks Mosaic's (8, 128) tiling rule.
 
-def _densify_block(vals, idx, d):
-    b, k = vals.shape
-    iota = jax.lax.broadcasted_iota(jnp.int32, (b, d), 1)
-    out = jnp.zeros((b, d), jnp.float32)
-    for t in range(k):
-        hit = (iota == idx[:, t][:, None]).astype(jnp.float32)
-        out = out + hit * vals[:, t][:, None].astype(jnp.float32)
-    return out
+
+def _init_state(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _online_update(s, vb, m_ref, l_ref, acc_ref):
+    """One online-softmax step: (1, bn) scores against a (bn, dv) V tile."""
+    m_prev = m_ref[:, :1]                                    # (1, 1)
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    l_new = l_ref[:, :1] * corr + p.sum(axis=-1, keepdims=True)
+    pv = jax.lax.dot_general(p, vb, (((1,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)  # (1, dv)
+    acc_ref[...] = acc_ref[...] * corr + pv
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+
+def _finalize(o_ref, l_ref, acc_ref):
+    o_ref[0] = (acc_ref[...] /
+                jnp.maximum(l_ref[:, :1], 1e-30)).astype(o_ref.dtype)
+
+
+def _state_scratch(dv):
+    return [pltpu.VMEM((1, LANES), jnp.float32),
+            pltpu.VMEM((1, LANES), jnp.float32),
+            pltpu.VMEM((1, dv), jnp.float32)]
+
+
+def _token_scores(q_ref, kv, ki, *, d, scale, start, block_n, length):
+    """(1, bn) masked scores of the query row against one code tile."""
+    kd = _densify_block(kv, ki.astype(jnp.int32), d)         # (bn, d)
+    q = q_ref[0].astype(jnp.float32)                         # (1, d)
+    s = jax.lax.dot_general(
+        q, kd, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale          # (1, bn)
+    pos = start + jax.lax.broadcasted_iota(jnp.int32, (1, block_n), 1)
+    return jnp.where(pos < length, s, NEG_INF)
 
 
 # --------------------------------------------------------------------------
@@ -75,35 +112,18 @@ def _decode_kernel(len_ref, q_ref, kv_ref, ki_ref, v_ref, o_ref,
 
     @pl.when(nb == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_state(m_ref, l_ref, acc_ref)
 
     @pl.when(nb * block_n < length)
     def _compute():
-        kd = _densify_block(kv_ref[0], ki_ref[0], d)            # (bn, d)
-        q = q_ref[...].astype(jnp.float32)                      # (1, d)
-        s = jax.lax.dot_general(
-            q, kd, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale          # (1, bn)
-        pos = nb * block_n + jax.lax.broadcasted_iota(jnp.int32, (1, block_n), 1)
-        s = jnp.where(pos < length, s, NEG_INF)
-        m_prev = m_ref[0, 0]
-        m_new = jnp.maximum(m_prev, s.max())
-        p = jnp.exp(s - m_new)                                   # (1, bn)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_ref[0, 0] * corr + p.sum()
-        vb = v_ref[0].astype(jnp.float32)                        # (bn, dv)
-        pv = jax.lax.dot_general(p, vb, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)  # (1, dv)
-        acc_ref[...] = acc_ref[...] * corr + pv
-        m_ref[...] = jnp.full_like(m_ref, m_new)
-        l_ref[...] = jnp.full_like(l_ref, l_new)
+        s = _token_scores(q_ref, kv_ref[0], ki_ref[0], d=d, scale=scale,
+                          start=nb * block_n, block_n=block_n, length=length)
+        _online_update(s, v_ref[0].astype(jnp.float32), m_ref, l_ref,
+                       acc_ref)
 
     @pl.when(nb == nnb - 1)
-    def _finalize():
-        o_ref[...] = (acc_ref[...] /
-                         jnp.maximum(l_ref[0, 0], 1e-30)).astype(o_ref.dtype)
+    def _fin():
+        _finalize(o_ref, l_ref, acc_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("d", "scale", "block_n", "interpret"))
@@ -130,24 +150,20 @@ def flash_sfa_decode(q, k_vals, k_idx, v, lengths, *, d: int,
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, d), lambda b, n, L: (b, 0)),
+                pl.BlockSpec((1, 1, d), lambda b, n, L: (b, 0, 0)),
                 pl.BlockSpec((1, block_n, kk), lambda b, n, L: (b, n, 0)),
                 pl.BlockSpec((1, block_n, kk), lambda b, n, L: (b, n, 0)),
                 pl.BlockSpec((1, block_n, dv), lambda b, n, L: (b, n, 0)),
             ],
-            out_specs=pl.BlockSpec((1, dv), lambda b, n, L: (b, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((1, LANES), jnp.float32),
-                pltpu.VMEM((1, LANES), jnp.float32),
-                pltpu.VMEM((1, dv), jnp.float32),
-            ],
+            out_specs=pl.BlockSpec((1, 1, dv), lambda b, n, L: (b, 0, 0)),
+            scratch_shapes=_state_scratch(dv),
         ),
-        out_shape=jax.ShapeDtypeStruct((bh, dv), v.dtype),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((bh, 1, dv), v.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=resolve_interpret(interpret),
-    )(jnp.asarray(lengths, jnp.int32), q, k_vals, k_idx, v)
-    return out
+    )(jnp.asarray(lengths, jnp.int32), q[:, None], k_vals, k_idx, v)
+    return out[:, 0]
 
 
 def _decode_paged_kernel(bt_ref, len_ref, q_ref, kv_ref, ki_ref, v_ref, o_ref,
@@ -160,37 +176,21 @@ def _decode_paged_kernel(bt_ref, len_ref, q_ref, kv_ref, ki_ref, v_ref, o_ref,
 
     @pl.when(nb == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_state(m_ref, l_ref, acc_ref)
 
     @pl.when(nb * page < length)
     def _compute():
         # kv/ki blocks are pool page bt[slot, nb] (index-map fetched);
         # indices are stored packed — unpack in VMEM, not the whole pool
-        kd = _densify_block(kv_ref[0, 0], ki_ref[0, 0].astype(jnp.int32), d)
-        q = q_ref[...].astype(jnp.float32)                   # (1, d)
-        s = jax.lax.dot_general(
-            q, kd, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale       # (1, page)
-        pos = nb * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
-        s = jnp.where(pos < length, s, NEG_INF)
-        m_prev = m_ref[0, 0]
-        m_new = jnp.maximum(m_prev, s.max())
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_ref[0, 0] * corr + p.sum()
-        vb = v_ref[0, 0].astype(jnp.float32)                 # (page, dv)
-        pv = jax.lax.dot_general(p, vb, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * corr + pv
-        m_ref[...] = jnp.full_like(m_ref, m_new)
-        l_ref[...] = jnp.full_like(l_ref, l_new)
+        s = _token_scores(q_ref, kv_ref[0, 0], ki_ref[0, 0], d=d,
+                          scale=scale, start=nb * page, block_n=page,
+                          length=length)
+        _online_update(s, v_ref[0, 0].astype(jnp.float32), m_ref, l_ref,
+                       acc_ref)
 
     @pl.when(nb == nnb - 1)
-    def _finalize():
-        o_ref[...] = (acc_ref[...] /
-                         jnp.maximum(l_ref[0, 0], 1e-30)).astype(o_ref.dtype)
+    def _fin():
+        _finalize(o_ref, l_ref, acc_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("d", "scale", "heads",
@@ -222,7 +222,7 @@ def flash_sfa_decode_paged(q, kv_pool, ki_pool, v_pool, block_tables,
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, d), lambda b, n, bt, L: (b, 0)),
+                pl.BlockSpec((1, 1, d), lambda b, n, bt, L: (b, 0, 0)),
                 # block-table indirection: grid step n streams pool page
                 # bt[slot, n] of the slot's kv head — same tile, same order
                 # as the contiguous kernel's (b, n) block
@@ -236,20 +236,17 @@ def flash_sfa_decode_paged(q, kv_pool, ki_pool, v_pool, block_tables,
                              lambda b, n, bt, L: ((b % heads) // group,
                                                   bt[b // heads, n], 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, dv), lambda b, n, bt, L: (b, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((1, LANES), jnp.float32),
-                pltpu.VMEM((1, LANES), jnp.float32),
-                pltpu.VMEM((1, dv), jnp.float32),
-            ],
+            out_specs=pl.BlockSpec((1, 1, dv),
+                                   lambda b, n, bt, L: (b, 0, 0)),
+            scratch_shapes=_state_scratch(dv),
         ),
-        out_shape=jax.ShapeDtypeStruct((bh, dv), jnp.float32),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((bh, 1, dv), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=resolve_interpret(interpret),
     )(jnp.asarray(block_tables, jnp.int32), jnp.asarray(lengths, jnp.int32),
-      q, kv_pool, ki_pool, v_pool)
-    return out
+      q[:, None], kv_pool, ki_pool, v_pool)
+    return out[:, 0]
 
 
 def _decode_multi_kernel(len_ref, q_ref, kv_ref, ki_ref, v_ref, o_ref,
@@ -262,35 +259,18 @@ def _decode_multi_kernel(len_ref, q_ref, kv_ref, ki_ref, v_ref, o_ref,
 
     @pl.when(nb == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_state(m_ref, l_ref, acc_ref)
 
     @pl.when(nb * block_n < length)
     def _compute():
-        kd = _densify_block(kv_ref[0], ki_ref[0].astype(jnp.int32), d)
-        q = q_ref[...].astype(jnp.float32)                      # (1, d)
-        s = jax.lax.dot_general(
-            q, kd, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale          # (1, bn)
-        pos = nb * block_n + jax.lax.broadcasted_iota(jnp.int32, (1, block_n), 1)
-        s = jnp.where(pos < length, s, NEG_INF)
-        m_prev = m_ref[0, 0]
-        m_new = jnp.maximum(m_prev, s.max())
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_ref[0, 0] * corr + p.sum()
-        vb = v_ref[0].astype(jnp.float32)                        # (bn, dv)
-        pv = jax.lax.dot_general(p, vb, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * corr + pv
-        m_ref[...] = jnp.full_like(m_ref, m_new)
-        l_ref[...] = jnp.full_like(l_ref, l_new)
+        s = _token_scores(q_ref, kv_ref[0], ki_ref[0], d=d, scale=scale,
+                          start=nb * block_n, block_n=block_n, length=length)
+        _online_update(s, v_ref[0].astype(jnp.float32), m_ref, l_ref,
+                       acc_ref)
 
     @pl.when(nb == nnb - 1)
-    def _finalize():
-        o_ref[...] = (acc_ref[...] /
-                         jnp.maximum(l_ref[0, 0], 1e-30)).astype(o_ref.dtype)
+    def _fin():
+        _finalize(o_ref, l_ref, acc_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("d", "scale", "heads", "block_n",
@@ -333,24 +313,20 @@ def flash_sfa_decode_multi(q, k_vals, k_idx, v, lengths, *, d: int,
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, d), lambda b, n, L: (b, 0)),
+                pl.BlockSpec((1, 1, d), lambda b, n, L: (b, 0, 0)),
                 pl.BlockSpec((1, block_n, kk), lambda b, n, L: (b % heads, n, 0)),
                 pl.BlockSpec((1, block_n, kk), lambda b, n, L: (b % heads, n, 0)),
                 pl.BlockSpec((1, block_n, dv), lambda b, n, L: (b % heads, n, 0)),
             ],
-            out_specs=pl.BlockSpec((1, dv), lambda b, n, L: (b, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((1, LANES), jnp.float32),
-                pltpu.VMEM((1, LANES), jnp.float32),
-                pltpu.VMEM((1, dv), jnp.float32),
-            ],
+            out_specs=pl.BlockSpec((1, 1, dv), lambda b, n, L: (b, 0, 0)),
+            scratch_shapes=_state_scratch(dv),
         ),
-        out_shape=jax.ShapeDtypeStruct((bh, dv), jnp.float32),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((bh, 1, dv), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=resolve_interpret(interpret),
-    )(jnp.asarray(lengths, jnp.int32), q, k_vals, k_idx, v)
-    return out
+    )(jnp.asarray(lengths, jnp.int32), q[:, None], k_vals, k_idx, v)
+    return out[:, 0]
 
 
 # --------------------------------------------------------------------------
@@ -375,6 +351,25 @@ def feature_major_prefill(k_vals, k_idx, d: int):
 
 
 
+def _feature_rows(image):
+    """(..., d, n) image -> (..., d, 1, n): one feature row per block.
+
+    Mosaic fetches whole (8, 128) tiles, so a single row of a (d, n) image
+    cannot be a block; with a unit second-minor axis it can. On the chip
+    this reshape is a relayout copy of the whole image per call — the
+    persistent cache should store this layout itself (ROADMAP, Speed 1).
+    """
+    return image[..., None, :]
+
+
+def _code_value(qv_ref, t):
+    """(1, 1) value of the query code's slot ``t`` (a grid index): a masked
+    lane sum, since a dynamic lane index into a vector does not lower."""
+    qv = qv_ref[0].astype(jnp.float32)                       # (1, k)
+    lane = jax.lax.broadcasted_iota(jnp.int32, qv.shape, 1)
+    return jnp.sum(jnp.where(lane == t, qv, 0.0), axis=-1, keepdims=True)
+
+
 def _decode_fm_kernel(qi_ref, len_ref, qv_ref, kf_ref, v_ref, o_ref,
                       s_ref, m_ref, l_ref, acc_ref, *, scale: float,
                       block_n: int, kq: int):
@@ -386,9 +381,7 @@ def _decode_fm_kernel(qi_ref, len_ref, qv_ref, kf_ref, v_ref, o_ref,
 
     @pl.when((nb == 0) & (t == 0))
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_state(m_ref, l_ref, acc_ref)
 
     @pl.when(t == 0)
     def _clear_scores():
@@ -397,31 +390,21 @@ def _decode_fm_kernel(qi_ref, len_ref, qv_ref, kf_ref, v_ref, o_ref,
     @pl.when(nb * block_n < length)
     def _accumulate():
         # kf_ref block is the single feature row qi[b, t] of the cache:
-        # shape (1, 1, block_n). Accumulate qv[t] * K_feat[row, tile].
-        s_ref[...] = s_ref[...] + qv_ref[0, t].astype(jnp.float32) * \
-            kf_ref[0, 0].astype(jnp.float32)[None, :]
+        # shape (1, 1, 1, block_n). Accumulate qv[t] * K_feat[row, tile].
+        s_ref[...] = s_ref[...] + _code_value(qv_ref, t) * \
+            kf_ref[0, 0].astype(jnp.float32)
 
     @pl.when((t == kq - 1) & (nb * block_n < length))
     def _softmax_update():
         s = s_ref[...] * scale                                   # (1, bn)
         pos = nb * block_n + jax.lax.broadcasted_iota(jnp.int32, (1, block_n), 1)
         s = jnp.where(pos < length, s, NEG_INF)
-        m_prev = m_ref[0, 0]
-        m_new = jnp.maximum(m_prev, s.max())
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_ref[0, 0] * corr + p.sum()
-        vb = v_ref[0].astype(jnp.float32)                        # (bn, dv)
-        pv = jax.lax.dot_general(p, vb, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * corr + pv
-        m_ref[...] = jnp.full_like(m_ref, m_new)
-        l_ref[...] = jnp.full_like(l_ref, l_new)
+        _online_update(s, v_ref[0].astype(jnp.float32), m_ref, l_ref,
+                       acc_ref)
 
     @pl.when((nb == nnb - 1) & (t == kq - 1))
-    def _finalize():
-        o_ref[...] = (acc_ref[...] /
-                         jnp.maximum(l_ref[0, 0], 1e-30)).astype(o_ref.dtype)
+    def _fin():
+        _finalize(o_ref, l_ref, acc_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "block_n", "group",
@@ -457,30 +440,27 @@ def flash_sfa_decode_fm(q_vals, q_idx, k_feat, v, lengths, *,
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, kq), lambda b, n, t, qi, L: (b, 0)),
+                pl.BlockSpec((1, 1, kq), lambda b, n, t, qi, L: (b, 0, 0)),
                 # the magic: fetch exactly feature row qi[b, t] of the
                 # group's shared image
-                pl.BlockSpec((1, 1, block_n),
+                pl.BlockSpec((1, 1, 1, block_n),
                              lambda b, n, t, qi, L: (b // group,
-                                                     qi[b, t], n)),
+                                                     qi[b, t], 0, n)),
                 pl.BlockSpec((1, block_n, dv),
                              lambda b, n, t, qi, L: (b // group, n, 0)),
             ],
-            out_specs=pl.BlockSpec((1, dv), lambda b, n, t, qi, L: (b, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((1, block_n), jnp.float32),
-                pltpu.VMEM((1, LANES), jnp.float32),
-                pltpu.VMEM((1, LANES), jnp.float32),
-                pltpu.VMEM((1, dv), jnp.float32),
-            ],
+            out_specs=pl.BlockSpec((1, 1, dv),
+                                   lambda b, n, t, qi, L: (b, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((1, block_n), jnp.float32),
+                            *_state_scratch(dv)],
         ),
-        out_shape=jax.ShapeDtypeStruct((bh, dv), jnp.float32),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((bh, 1, dv), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=resolve_interpret(interpret),
     )(jnp.asarray(q_idx, jnp.int32), jnp.asarray(lengths, jnp.int32),
-      q_vals, k_feat, v)
-    return out
+      q_vals[:, None], _feature_rows(k_feat), v)
+    return out[:, 0]
 
 
 def _decode_fm_paged_kernel(qi_ref, bt_ref, len_ref, qv_ref, kf_ref, v_ref,
@@ -494,9 +474,7 @@ def _decode_fm_paged_kernel(qi_ref, bt_ref, len_ref, qv_ref, kf_ref, v_ref,
 
     @pl.when((nb == 0) & (t == 0))
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_state(m_ref, l_ref, acc_ref)
 
     @pl.when(t == 0)
     def _clear_scores():
@@ -505,31 +483,21 @@ def _decode_fm_paged_kernel(qi_ref, bt_ref, len_ref, qv_ref, kf_ref, v_ref,
     @pl.when(nb * page < length)
     def _accumulate():
         # kf block is feature row qi[b, t] of pool page bt[slot, nb]:
-        # shape (1, 1, 1, page)
-        s_ref[...] = s_ref[...] + qv_ref[0, t].astype(jnp.float32) * \
-            kf_ref[0, 0, 0].astype(jnp.float32)[None, :]
+        # shape (1, 1, 1, 1, page)
+        s_ref[...] = s_ref[...] + _code_value(qv_ref, t) * \
+            kf_ref[0, 0, 0].astype(jnp.float32)
 
     @pl.when((t == kq - 1) & (nb * page < length))
     def _softmax_update():
         s = s_ref[...] * scale                                # (1, page)
         pos = nb * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
         s = jnp.where(pos < length, s, NEG_INF)
-        m_prev = m_ref[0, 0]
-        m_new = jnp.maximum(m_prev, s.max())
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_ref[0, 0] * corr + p.sum()
-        vb = v_ref[0, 0].astype(jnp.float32)                  # (page, dv)
-        pv = jax.lax.dot_general(p, vb, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * corr + pv
-        m_ref[...] = jnp.full_like(m_ref, m_new)
-        l_ref[...] = jnp.full_like(l_ref, l_new)
+        _online_update(s, v_ref[0, 0].astype(jnp.float32), m_ref, l_ref,
+                       acc_ref)
 
     @pl.when((nb == nnb - 1) & (t == kq - 1))
-    def _finalize():
-        o_ref[...] = (acc_ref[...] /
-                         jnp.maximum(l_ref[0, 0], 1e-30)).astype(o_ref.dtype)
+    def _fin():
+        _finalize(o_ref, l_ref, acc_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "heads", "interpret"))
@@ -560,29 +528,26 @@ def flash_sfa_decode_fm_paged(q_vals, q_idx, kf_pool, v_pool, block_tables,
             num_scalar_prefetch=3,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, kq), lambda b, n, t, qi, bt, L: (b, 0)),
-                pl.BlockSpec((1, 1, 1, page),
+                pl.BlockSpec((1, 1, kq), lambda b, n, t, qi, bt, L: (b, 0, 0)),
+                pl.BlockSpec((1, 1, 1, 1, page),
                              lambda b, n, t, qi, bt, L: (
                                  (b % heads) // group,
-                                 bt[b // heads, n], qi[b, t], 0)),
+                                 bt[b // heads, n], qi[b, t], 0, 0)),
                 pl.BlockSpec((1, 1, page, dv),
                              lambda b, n, t, qi, bt, L: (
                                  (b % heads) // group,
                                  bt[b // heads, n], 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, dv),
-                                   lambda b, n, t, qi, bt, L: (b, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((1, page), jnp.float32),
-                pltpu.VMEM((1, LANES), jnp.float32),
-                pltpu.VMEM((1, LANES), jnp.float32),
-                pltpu.VMEM((1, dv), jnp.float32),
-            ],
+            out_specs=pl.BlockSpec((1, 1, dv),
+                                   lambda b, n, t, qi, bt, L: (b, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((1, page), jnp.float32),
+                            *_state_scratch(dv)],
         ),
-        out_shape=jax.ShapeDtypeStruct((bh, dv), jnp.float32),
-        compiler_params=CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((bh, 1, dv), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=resolve_interpret(interpret),
     )(jnp.asarray(q_idx, jnp.int32), jnp.asarray(block_tables, jnp.int32),
-      jnp.asarray(lengths, jnp.int32), q_vals, kf_pool, v_pool)
-    return out
+      jnp.asarray(lengths, jnp.int32), q_vals[:, None], _feature_rows(kf_pool),
+      v_pool)
+    return out[:, 0]

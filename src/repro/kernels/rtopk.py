@@ -38,8 +38,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams, resolve_interpret
+from repro.kernels._compat import resolve_interpret
+from repro.kernels.flash_sfa import row_to_column
 
 
 def _cumsum_rows(x: jax.Array) -> jax.Array:
@@ -142,7 +144,7 @@ def rtopk(x: jax.Array, k: int, *, block_rows: int = 256,
             jax.ShapeDtypeStruct((x2.shape[0], k), x.dtype),
             jax.ShapeDtypeStruct((x2.shape[0], k), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x2)
@@ -153,27 +155,29 @@ def rtopk(x: jax.Array, k: int, *, block_rows: int = 256,
 
 def _rope_tile(y: jax.Array, pos: jax.Array, theta: float, rot: int,
                dt) -> jax.Array:
-    """RoPE on one (br, d) projection tile — same op sequence as
+    """RoPE on one (br, d) projection tile — same arithmetic as
     ``models.layers.rope`` (elementwise, so the fused forward stays parity-
-    exact with the unfused projection -> rope -> rtopk composition)."""
+    exact with the unfused projection -> rope -> rtopk composition).
+
+    pos: (br, 1) f32 positions. The interleaved pairs (2i, 2i+1) are
+    rotated lane-wise, with no lane reshape: each lane reads its partner
+    through a lane roll, and a parity mask picks the sign of the sin term.
+    """
     br, d = y.shape
-    y = y.astype(dt)                               # unfused path ropes dt acts
+    yf = y.astype(dt).astype(jnp.float32)          # unfused path ropes dt acts
     # iota, not jnp.arange: arange would be a captured trace-time constant,
     # which pallas kernels reject.
-    half = jax.lax.broadcasted_iota(jnp.float32, (1, rot // 2), 1)
-    freqs = theta ** (-(2.0 * half) / rot)
-    ang = pos[:, None].astype(jnp.float32) * freqs          # (br, rot/2)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, d), 1)
+    freqs = theta ** (-(2.0 * (lane // 2).astype(jnp.float32)) / rot)
+    ang = pos * freqs                                       # (br, d)
     cos = jnp.cos(ang)
     sin = jnp.sin(ang)
-    pairs = y[:, :rot].astype(jnp.float32).reshape(br, rot // 2, 2)
-    x1 = pairs[:, :, 0]
-    x2 = pairs[:, :, 1]
-    r1 = x1 * cos - x2 * sin
-    r2 = x2 * cos + x1 * sin
-    rotated = jnp.stack([r1, r2], axis=-1).reshape(br, rot)
+    even = lane % 2 == 0
+    nxt = pltpu.roll(yf, d - 1, 1)                          # lane j <- j+1
+    prv = pltpu.roll(yf, 1, 1)                              # lane j <- j-1
+    rotated = jnp.where(even, yf * cos - nxt * sin, yf * cos + prv * sin)
     if rot < d:
-        rotated = jnp.concatenate(
-            [rotated, y[:, rot:].astype(jnp.float32)], axis=-1)
+        rotated = jnp.where(lane < rot, rotated, yf)
     return rotated.astype(dt)
 
 
@@ -191,7 +195,8 @@ def _proj_rtopk_kernel(x_ref, w_ref, *rest, k: int, rope_spec):
     y = y.astype(dt)                               # quantize like `x @ w`
     if rope_spec is not None:
         theta, rot = rope_spec
-        y = _rope_tile(y, pos_ref[0], theta, rot, dt)
+        pos = row_to_column(pos_ref[0].astype(jnp.float32))   # (bn, 1)
+        y = _rope_tile(y, pos, theta, rot, dt)
     vals, idx = _topk_select(y.astype(jnp.float32), k)
     vals_ref[0, 0] = vals.astype(dt)
     idx_ref[0, 0] = idx
@@ -233,8 +238,10 @@ def proj_rtopk(x: jax.Array, w_heads: jax.Array, positions=None, *, k: int,
         pos = jnp.broadcast_to(positions, (b, n)).astype(jnp.int32)
         if pad:
             pos = jnp.pad(pos, ((0, 0), (0, pad)))
-        in_specs.append(pl.BlockSpec((1, block_n),
-                                     lambda bb, hh, ii: (bb, ii)))
+        # (b, 1, n) rows: see flash_sfa.lanes_to_row
+        pos = pos[:, None, :]
+        in_specs.append(pl.BlockSpec((1, 1, block_n),
+                                     lambda bb, hh, ii: (bb, 0, ii)))
         operands.append(pos)
     vals, idx = pl.pallas_call(
         functools.partial(_proj_rtopk_kernel, k=k, rope_spec=rope_spec),
@@ -250,7 +257,7 @@ def proj_rtopk(x: jax.Array, w_heads: jax.Array, positions=None, *, k: int,
             jax.ShapeDtypeStruct((b, nh, np_, k), x.dtype),
             jax.ShapeDtypeStruct((b, nh, np_, k), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
     )(*operands)

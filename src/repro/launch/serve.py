@@ -7,6 +7,9 @@
     PYTHONPATH=src python -m repro.launch.serve --arch gpt2-small-sfa8 \
         --paged --mem-budget-mb 64 --prefill-chunk 128
 
+``--full`` serves the published widths (the default is the reduced
+CPU-size config).
+
 ``--decode-backend`` selects the serving attention kernel through the
 backend registry (repro/models/backends.py): ``pallas`` = token-major
 ``flash_sfa_decode``, ``pallas_fm`` = feature-major on the persistent
@@ -41,6 +44,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core.kv_cache import kv_cache_nodes
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import init as model_init
 from repro.models.backends import fallback_reports, set_fm_debug
 from repro.serve import (DecodeEngine, EngineConfig, PagedDecodeEngine,
@@ -80,8 +84,12 @@ def main():
     ap.add_argument("--draft-k", type=int, default=None,
                     help="draft-pass sparse k' (default: sfa_k // 4)")
     ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--full", dest="reduced", action="store_false",
+                    help="serve the published widths instead of the "
+                         "reduced CPU-size config")
     args = ap.parse_args()
 
+    use_compile_cache()
     if args.fm_debug:
         set_fm_debug(True)
     cfg = get_config(args.arch)

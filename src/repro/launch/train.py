@@ -3,26 +3,106 @@
     PYTHONPATH=src python -m repro.launch.train --arch llama3-8b \
         --steps 100 [--reduced] [--mesh debug|single-pod|multi-pod]
 
-On this CPU container only ``--reduced --mesh debug`` executes; the
-production mesh paths go through the same code but are exercised via
-``repro.launch.dryrun`` (lower+compile only). On a real TPU cluster the
-launcher runs per-host with jax.distributed initialization.
+``--mesh debug`` runs on the visible devices (one TPU chip, or the CPU at
+``--reduced`` size); the production meshes go through the same code and are
+exercised via ``repro.launch.dryrun`` (lower+compile only). On a TPU
+cluster the launcher runs per-host with jax.distributed initialization.
+
+``run_training`` is the launcher's whole path as a function, so
+``chip_smoke.py`` drives exactly what the command line drives.
 """
 import argparse
+import dataclasses
+import time
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config
-from repro.configs.base import TrainPolicy
+from repro.configs.base import ModelConfig, TrainPolicy
 from repro.data import DataConfig, markov_batch
 from repro.distributed.sharding import axis_rules
 from repro.launch import specs as S
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_debug_mesh, make_production_mesh
 from repro.models import init as model_init
 from repro.optim import OptimizerConfig, init_opt_state
 from repro.train.train_step import make_train_step
+
+
+@dataclasses.dataclass
+class TrainRun:
+    """What ``run_training`` hands back: per-step metrics, the compiled
+    step's HLO text and compile seconds, and the final parameters."""
+    metrics: list
+    compile_seconds: float
+    hlo_text: str
+    params: dict
+    cfg: ModelConfig
+    data: DataConfig
+
+
+def run_training(cfg: ModelConfig, *, steps: int, batch: int, seq_len: int,
+                 lr: float = 3e-3, mesh: str = "debug", tp: int = 1,
+                 ring: int = 1, devices=None, log=None,
+                 **policy_overrides) -> TrainRun:
+    """Build the mesh, params and jitted step, compile it, run ``steps``.
+
+    ``devices`` limits the debug mesh (default: every visible device);
+    ``policy_overrides`` are ``TrainPolicy`` fields (remat, bwd_emit,
+    fwd_fuse, backend). Parameters come from ``PRNGKey(0)`` and batch ``s``
+    from ``markov_batch(.., s)``, so two runs see the same weights and data.
+    """
+    if mesh != "debug" and (tp > 1 or ring > 1):
+        raise ValueError("--tp/--ring shape the debug mesh only; production "
+                         "meshes fix their own axes (launch/mesh.py)")
+    mesh = (make_debug_mesh(model=tp, seq=ring, devices=devices)
+            if mesh == "debug" else
+            make_production_mesh(multi_pod=mesh == "multi-pod"))
+    overrides = {"tp": tp, **{k: v for k, v in policy_overrides.items()
+                              if v is not None}}
+    if ring > 1:
+        overrides["ring"] = True
+    policy = TrainPolicy.from_model(cfg, **overrides)
+
+    with mesh, axis_rules(mesh):
+        params = model_init(jax.random.PRNGKey(0), cfg)
+        opt = init_opt_state(params)
+        pspec = S.param_specs(params, cfg, mesh)
+        sh = lambda t: jax.tree.map(lambda s: NamedSharding(mesh, s), t,
+                                    is_leaf=lambda x: isinstance(x, P))
+        ocfg = OptimizerConfig(lr=lr, warmup_steps=max(steps // 10, 2),
+                               total_steps=steps)
+        dcfg = DataConfig(vocab_size=cfg.vocab_size, seq_len=seq_len,
+                          global_batch=batch)
+        step = jax.jit(
+            make_train_step(cfg, ocfg, policy=policy),
+            in_shardings=(sh(pspec),
+                          sh(type(opt)(step=P(), m=pspec, v=pspec)),
+                          None),
+            # pin outputs to the input layouts: the shard_map'd kernel
+            # paths can tip GSPMD's inference toward resharding a param's
+            # round-trip, which donation then rejects
+            out_shardings=(sh(pspec),
+                           sh(type(opt)(step=P(), m=pspec, v=pspec)),
+                           None),
+            donate_argnums=(0, 1))
+        batches = ({k: jnp.asarray(v) for k, v in markov_batch(dcfg, s).items()}
+                   for s in range(steps))
+        first = next(batches)
+        t0 = time.perf_counter()
+        compiled = step.lower(params, opt, first).compile()
+        compile_s = time.perf_counter() - t0
+        metrics = []
+        for s, b in enumerate([first, *batches]):
+            params, opt, m = compiled(params, opt, b)
+            metrics.append({k: float(v) for k, v in m.items()})
+            if log is not None:
+                log(s, metrics[-1])
+    return TrainRun(metrics=metrics, compile_seconds=compile_s,
+                    hlo_text=compiled.as_text(), params=params, cfg=cfg,
+                    data=dcfg)
 
 
 def main():
@@ -73,56 +153,24 @@ def main():
                          "they summarize (DESIGN.md §10)")
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    if args.mesh != "debug" and (args.tp > 1 or args.ring > 1):
-        raise SystemExit("--tp/--ring shape the debug mesh only; production "
-                         "meshes fix their own axes (launch/mesh.py)")
-    mesh = (make_debug_mesh(model=args.tp, seq=args.ring)
-            if args.mesh == "debug" else
-            make_production_mesh(multi_pod=args.mesh == "multi-pod"))
+    every = max(args.steps // 10, 1)
 
-    with mesh, axis_rules(mesh):
-        params = model_init(jax.random.PRNGKey(0), cfg)
-        opt = init_opt_state(params)
-        pspec = S.param_specs(params, cfg, mesh)
-        sh = lambda t: jax.tree.map(lambda s: NamedSharding(mesh, s), t,
-                                    is_leaf=lambda x: isinstance(x, P))
-        ocfg = OptimizerConfig(lr=args.lr, warmup_steps=max(args.steps // 10, 2),
-                               total_steps=args.steps)
-        dcfg = DataConfig(vocab_size=cfg.vocab_size, seq_len=args.seq_len,
-                          global_batch=args.batch)
-        overrides = {"tp": args.tp, "backend": args.attn_backend}
-        if args.remat is not None:
-            overrides["remat"] = args.remat
-        if args.bwd_emit is not None:
-            overrides["bwd_emit"] = args.bwd_emit
-        if args.fwd_fuse is not None:
-            overrides["fwd_fuse"] = args.fwd_fuse
-        if args.ring > 1:
-            overrides["ring"] = True
-        policy = TrainPolicy.from_model(cfg, **overrides)
-        step = jax.jit(
-            make_train_step(cfg, ocfg, policy=policy),
-            in_shardings=(sh(pspec),
-                          sh(type(opt)(step=P(), m=pspec, v=pspec)),
-                          None),
-            # pin outputs to the input layouts: the shard_map'd kernel
-            # paths can tip GSPMD's inference toward resharding a param's
-            # round-trip, which donation then rejects
-            out_shardings=(sh(pspec),
-                           sh(type(opt)(step=P(), m=pspec, v=pspec)),
-                           None),
-            donate_argnums=(0, 1))
-        for s in range(args.steps):
-            batch = {k: jnp.asarray(v) for k, v in
-                     markov_batch(dcfg, s).items()}
-            params, opt, m = step(params, opt, batch)
-            if s % max(args.steps // 10, 1) == 0:
-                print(f"step {s:4d} loss {float(m['loss']):.4f} "
-                      f"gnorm {float(m['grad_norm']):.3f}")
-        print(f"done: final loss {float(m['loss']):.4f}")
+    def log(s, m):
+        if s % every == 0:
+            print(f"step {s:4d} loss {m['loss']:.4f} "
+                  f"gnorm {m['grad_norm']:.3f}")
+
+    run = run_training(cfg, steps=args.steps, batch=args.batch,
+                       seq_len=args.seq_len, lr=args.lr, mesh=args.mesh,
+                       tp=args.tp, ring=args.ring, log=log,
+                       backend=args.attn_backend, remat=args.remat,
+                       bwd_emit=args.bwd_emit, fwd_fuse=args.fwd_fuse)
+    print(f"compile {run.compile_seconds:.1f} s")
+    print(f"done: final loss {run.metrics[-1]['loss']:.4f}")
 
 
 if __name__ == "__main__":

@@ -10,8 +10,10 @@ Three call modes share parameters:
                          pattern the roofline measures).
   * ``mode="chunk"``   — chunked prefill for the paged serving engine: a
                          chunk of one slot's prompt lands via ``write_chunk``
-                         and is scored as vmapped single-token oracle
-                         decodes at per-query prefix lengths (DESIGN.md §5).
+                         and is scored through the decode backend's
+                         multi-token ``verify`` entry, each query a
+                         single-token decode at its own prefix length
+                         (DESIGN.md §5).
 
 Execution backends are resolved through the typed registry
 (``repro.models.backends``): ``cfg.attention.backend`` selects the
@@ -52,7 +54,7 @@ from repro.core.kv_cache import (
 )
 from repro.core.sparse import topk_st, sparsify, SparseCode
 from repro.distributed.ring import ring_degree, ring_sfa_op
-from repro.distributed.shard import replicate, tp_flash_sfa, tp_flash_sfa_bwd
+from repro.distributed.shard import tp_flash_sfa, tp_flash_sfa_bwd
 from repro.distributed.sharding import axis_size, constrain
 from repro.kernels.flash_sfa_bwd import pair_closure_indices
 from repro.kernels.flash_sfa_decode import LANES as _FM_TILE, \
@@ -61,7 +63,7 @@ from repro.kernels.ops import (
     _sfa_pallas_fwd, fold_heads, fused_qk_codes, unfold_heads,
 )
 from repro.models.backends import (
-    AttentionRequest, DecodeQuery, expand_kv as _expand_kv, get_backend,
+    AttentionRequest, DecodeQuery, expand_kv as _expand_kv,
     resolve_backend_name, select_backend,
 )
 from repro.models.layers import (
@@ -485,13 +487,9 @@ def _sfa_proj_attend_bwd(h, hkv, hd, sfa_k, causal, scale, rope_spec,
     dv32 = dv_flat.astype(jnp.float32)
     dx_v = dv32 @ wv.astype(jnp.float32).T
     dwv = x_flat.astype(jnp.float32).T @ dv32
-    # The dW blocks are weight-sized: pin the TP-sharded q/k pieces back to
-    # replicated before joining them with the (replicated) v piece — see
-    # distributed/shard.py::replicate for why the mixed-sharding concat is
-    # unsafe under a multi-axis mesh.
     dw = jnp.concatenate(
-        [replicate(jnp.moveaxis(dwq, 0, 1).reshape(m, h * hd)),
-         replicate(jnp.moveaxis(dwk, 0, 1).reshape(m, hkv * hd)), dwv],
+        [jnp.moveaxis(dwq, 0, 1).reshape(m, h * hd),
+         jnp.moveaxis(dwk, 0, 1).reshape(m, hkv * hd), dwv],
         axis=1).astype(w.dtype)
     dx = (dx_q + dx_k + dx_v).reshape(b, n, m).astype(x.dtype)
     # positions are integer coordinates: their cotangent is the float0 zero
@@ -713,15 +711,16 @@ def attention_apply(params, x, *, cfg: ModelConfig, positions=None,
         o = ctx.astype(dt).reshape(b, 1, h * hd)
         return AttentionOut(dense(params["w_o"], o, dt), cache)
 
-    if mode == "verify":
-        # speculative verify: land all C = draft_len + 1 tokens' FULL-k
-        # codes (overwriting the draft pass's low-k' decode writes — the
-        # K/V-resolution half of the rewind contract, DESIGN.md §6), then
-        # score every query at its own causal length in ONE batched pass
-        # through the backend's multi-token verify entry point. Same
-        # write/gather machinery as chunked prefill; only the scoring hop
-        # differs (backends without the capability fall back to the oracle
-        # with a structured report — exactly the chunk path's arithmetic).
+    if mode in ("chunk", "verify"):
+        # chunked prefill / speculative verify: land C tokens of one slot
+        # (prompt tokens, or the draft's FULL-k codes overwriting its low-k'
+        # decode writes — the K/V-resolution half of the rewind contract,
+        # DESIGN.md §6), then score every query at its own causal length
+        # (query i sees cache_len + i + 1 tokens) in ONE batched pass
+        # through the backend's multi-token verify entry point. Each query
+        # is exactly a single-token decode, so chunk boundaries never
+        # change which tokens are visible. Backends without the capability
+        # fall back to the oracle with a structured report.
         assert cache is not None and cache_len is not None and slot is not None
         if a.sfa_k is not None:
             p = a.sfa_rope_protect
@@ -743,34 +742,6 @@ def attention_apply(params, x, *, cfg: ModelConfig, positions=None,
                                  window=window, sfa_k=a.sfa_k,
                                  rope_protect=a.sfa_rope_protect,
                                  block_n=block_n)
-        o = ctx.astype(dt).reshape(1, n, h * hd)
-        return AttentionOut(dense(params["w_o"], o, dt), cache)
-
-    if mode == "chunk":
-        # chunked prefill: land C prompt tokens of one slot into the paged
-        # cache, then score each chunk query as a single-token oracle decode
-        # at its own prefix length (query i sees cache_len + i + 1 tokens) —
-        # exact reuse of the decode math, so chunk boundaries never change
-        # which tokens are visible. Prefill-side compute, oracle by design.
-        assert cache is not None and cache_len is not None and slot is not None
-        if a.sfa_k is not None:
-            p = a.sfa_rope_protect
-            kc = _sfa_code(k, a)                      # (b, C, hkv, k)
-            cache = cache.write_chunk(slot, cache_len, k_vals=kc.values,
-                                      k_idx=kc.indices, v=v,
-                                      k_protect=k[..., :p] if p else None)
-        else:
-            cache = cache.write_chunk(slot, cache_len, k=k, v=v)
-        g = cache.gather_slot(slot)                   # batch-1 contiguous
-        oracle = get_backend("xla")
-        lens = cache_len + jnp.arange(n)              # (C,)
-
-        def one(qi, li):
-            return oracle.decode(DecodeQuery(q=qi[None, None]), g, li[None],
-                                 scale=scale, window=window, sfa_k=a.sfa_k,
-                                 rope_protect=a.sfa_rope_protect)[0]
-
-        ctx = jax.vmap(one)(q[0], lens)               # (C, h, dv)
         o = ctx.astype(dt).reshape(1, n, h * hd)
         return AttentionOut(dense(params["w_o"], o, dt), cache)
 

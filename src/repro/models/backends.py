@@ -64,7 +64,6 @@ from repro.kernels.flash_sfa_decode import (
 from repro.kernels.ops import dense_attention_op, sfa_attention_op
 
 _LOG = logging.getLogger(__name__)
-_ON_TPU = jax.default_backend() == "tpu"
 
 
 # --------------------------------------------------------------------------
@@ -601,9 +600,13 @@ register_backend(XLABackend())
 register_backend(PallasBackend())
 register_backend(PallasFMBackend())
 
-# auto-selection preference: compiled Pallas kernels on TPU; the XLA paths
-# everywhere else (interpret-mode Pallas is a correctness tool, not serving)
-_AUTO_ORDER = ("pallas", "xla") if _ON_TPU else ("xla", "pallas")
+def _auto_order() -> tuple:
+    """auto-selection preference: compiled Pallas kernels on TPU; the XLA
+    paths everywhere else (interpret-mode Pallas is a correctness tool, not
+    serving). Asked at selection time, not at import, so the answer follows
+    the backend JAX actually initialized."""
+    return ("pallas", "xla") if jax.default_backend() == "tpu" \
+        else ("xla", "pallas")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -644,7 +647,7 @@ def resolve_backend_name(name: str, req: AttentionRequest) -> str:
     whether the stack's forward runs through the code-tagging pallas paths
     without charging a FallbackReport to a site that never traces)."""
     if name == "auto":
-        for nm in _AUTO_ORDER:
+        for nm in _auto_order():
             b = _REGISTRY.get(nm)
             if b is not None and b.unsupported_reason(req) is None:
                 return nm
@@ -664,7 +667,7 @@ def select_backend(name: str, req: AttentionRequest, *,
     code spread across trace-time ``warnings.warn`` calls.
     """
     if name == "auto":
-        for nm in _AUTO_ORDER:
+        for nm in _auto_order():
             b = _REGISTRY.get(nm)
             if b is not None and b.unsupported_reason(req) is None:
                 return BackendSelection(b, "auto")

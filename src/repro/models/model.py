@@ -401,7 +401,8 @@ def prefill_chunk(params, tokens, caches, offset, valid, slot, cfg: ModelConfig)
     logits at the last *valid* chunk position (``valid <= C``; trailing pad
     tokens are written but always masked/overwritten before any read).
 
-    Chunk scoring reuses the single-token decode oracle per query (see
+    Each chunk query is scored as a single-token decode at its own prefix
+    length, through the decode backend's multi-token verify entry (see
     ``attention_apply`` mode="chunk"), so interleaving chunks with decode
     steps never changes which cache prefix a query sees."""
     dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32

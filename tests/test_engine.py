@@ -165,12 +165,14 @@ def test_eos_termination(dense_setup):
     ref = _engine(cfg, params).generate(np.array([1, 2, 3], np.int64),
                                         max_new_tokens=8)
     assert len(ref) == 8
-    # greedy decode is deterministic: re-running with eos_id = the 4th token
-    # must stop exactly there, keeping the EOS token itself
-    eos = ref[3]
-    out = _engine(cfg, params, eos_id=eos).generate(
+    # greedy decode is deterministic: re-running with eos_id = a generated
+    # token must stop at its first occurrence, keeping the EOS token itself.
+    # Pick the first token past position 0 that has not appeared earlier, so
+    # that first occurrence is where it stands in ``ref``.
+    j = next((i for i in range(1, len(ref)) if ref[i] not in ref[:i]), 0)
+    out = _engine(cfg, params, eos_id=ref[j]).generate(
         np.array([1, 2, 3], np.int64), max_new_tokens=8)
-    assert out == ref[:4]
+    assert out == ref[:j + 1]
 
 
 def test_slot_isolation_batched_vs_solo(dense_setup):

@@ -22,6 +22,7 @@ Pinned here:
 import dataclasses
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -105,10 +106,10 @@ def _walk_eqns(jaxpr):
         for v in eqn.params.values():
             for sub in jax.tree_util.tree_leaves(
                     v, is_leaf=lambda x: isinstance(
-                        x, (jax.core.Jaxpr, jax.core.ClosedJaxpr))):
-                if isinstance(sub, jax.core.ClosedJaxpr):
+                        x, (jex_core.Jaxpr, jex_core.ClosedJaxpr))):
+                if isinstance(sub, jex_core.ClosedJaxpr):
                     yield from _walk_eqns(sub.jaxpr)
-                elif isinstance(sub, jax.core.Jaxpr):
+                elif isinstance(sub, jex_core.Jaxpr):
                     yield from _walk_eqns(sub)
 
 

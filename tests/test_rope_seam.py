@@ -351,10 +351,9 @@ def test_seam_tp_eligibility_matrix(monkeypatch):
 def test_seam_taken_under_tp2_grad_parity(rng):
     """Acceptance (ISSUE 9): on a real model=2 mesh the ``compact2`` seam
     is TAKEN (not fallen back) for divisible GQA heads and its weight/input
-    grads match the single-device run <= 1e-4 — including the dw
-    replication pin in ``_sfa_proj_attend_bwd`` (distributed/shard.py::
-    replicate) that keeps the concat of shard_map'd dwq/dwk with the
-    replicated dwv exact on a (data, model) mesh."""
+    grads match the single-device run <= 1e-4 — including the concat of
+    shard_map'd dwq/dwk with the replicated dwv in ``_sfa_proj_attend_bwd``
+    on a (data, model) mesh."""
     from repro.distributed.sharding import axis_rules
     from repro.launch.mesh import make_debug_mesh
 
