@@ -41,10 +41,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import scopes
 from repro.kernels._compat import resolve_interpret
 from repro.kernels.flash_sfa import _densify_block
 
 
+@jax.named_scope(scopes.CODE_GRAD)
 def scatter_code_grads(vals: jax.Array, idx: jax.Array, d: int) -> jax.Array:
     """XLA oracle: scatter (..., k) value-grads to their dense (..., d) form.
 
@@ -76,6 +78,7 @@ def _dx_kernel(vals_ref, idx_ref, w_ref, out_ref, acc_ref, *, d: int,
         out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
 
+@jax.named_scope(scopes.CODE_GRAD)
 @functools.partial(jax.jit, static_argnames=("d", "block_n", "block_m",
                                              "interpret"))
 def code_grad_dx(vals, idx, w, *, d: int, block_n: int = 128,
@@ -135,6 +138,7 @@ def _dw_kernel(x_ref, vals_ref, idx_ref, out_ref, acc_ref, *, d: int,
         out_ref[0, ...] = acc_ref[...].astype(out_ref.dtype)
 
 
+@jax.named_scope(scopes.CODE_GRAD)
 @functools.partial(jax.jit, static_argnames=("d", "block_n", "block_m",
                                              "interpret"))
 def code_grad_dw(x, vals, idx, *, d: int, block_n: int = 128,

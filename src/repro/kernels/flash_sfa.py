@@ -43,6 +43,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import scopes
 from repro.kernels._compat import resolve_interpret
 
 NEG_INF = -1e30
@@ -242,6 +243,7 @@ def _tile_occupancy(vals, idx, d: int, nblocks: int, block: int):
                   flat_idx].max(live, mode="drop")
 
 
+@jax.named_scope(scopes.BLOCK_MAPS)
 def _block_maps(q_vals, q_idx, k_vals, k_idx, *, d: int, causal: bool,
                 block_q: int, block_k: int, nq_real: int, nk_real: int):
     """Level map + fetch map for the block-skip kernel (padded inputs).
@@ -312,6 +314,7 @@ def block_skip_stats(q_vals, q_idx, k_vals, k_idx, *, d: int,
             (level == 2).sum() / total)
 
 
+@jax.named_scope(scopes.SFA_FWD)
 @functools.partial(jax.jit, static_argnames=(
     "d", "causal", "scale", "block_q", "block_k", "interpret",
     "return_residuals", "block_skip"))

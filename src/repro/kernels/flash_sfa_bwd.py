@@ -59,6 +59,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import scopes
 from repro.kernels._compat import resolve_interpret
 from repro.kernels.flash_sfa import _densify_block, row_to_column
 
@@ -343,6 +344,7 @@ def _bwd_impl(q_ops, k_ops, v, o, lse, g, *, d, causal, scale, block_q,
     return dq[:, :nq], dk[:, :nk], dvout[:, :nk]
 
 
+@jax.named_scope(scopes.SFA_BWD)
 @functools.partial(jax.jit, static_argnames=(
     "d", "causal", "scale", "block_q", "block_k", "interpret", "emit",
     "rot_dim"))

@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import attention as att
+from repro.core import scopes
 from repro.core.remat import tag_codes, tag_k_codes, tag_lse, tag_q_codes
 from repro.distributed.shard import (
     run_tp, tp_flash_sfa, tp_flash_sfa_bwd, tp_proj_rtopk,
@@ -86,23 +87,28 @@ def fused_qk_codes(x, w, positions, *, h, hkv, hd, sfa_k, rope_spec=None):
     here — only slicing, axis moves and repeats of (n, k)-sized arrays.
     """
     b, n, m = x.shape
-    w = w.astype(x.dtype)               # unfused path projects in x.dtype
-    wq = jnp.moveaxis(w[:, :h * hd].reshape(m, h, hd), 1, 0)
-    wk = jnp.moveaxis(w[:, h * hd:(h + hkv) * hd].reshape(m, hkv, hd), 1, 0)
-    qv, qi = tp_proj_rtopk(x, wq, positions, k=sfa_k, rope_spec=rope_spec)
-    kv_, ki = tp_proj_rtopk(x, wk, positions, k=sfa_k, rope_spec=rope_spec)
-    # name the codes as remat="codes" saveables (identity otherwise): the
-    # checkpoint policy saves these (n, k) tensors so the backward never
-    # re-runs the fused projection->rope->top-k pass (core/remat.py). The
-    # k codes are tagged BEFORE the GQA group-repeat — the policy stores
-    # them at hkv width and the backward recomputes the repeat for free.
-    qv, qi = tag_q_codes(qv, qi)
-    kv_, ki = tag_k_codes(kv_, ki)
-    if hkv != h:
-        kv_ = jnp.repeat(kv_, h // hkv, axis=1)
-        ki = jnp.repeat(ki, h // hkv, axis=1)
-    return (qv.reshape(b * h, n, sfa_k), qi.reshape(b * h, n, sfa_k),
-            kv_.reshape(b * h, n, sfa_k), ki.reshape(b * h, n, sfa_k))
+    with jax.named_scope(scopes.PROJ_RTOPK):
+        w = w.astype(x.dtype)           # unfused path projects in x.dtype
+        wq = jnp.moveaxis(w[:, :h * hd].reshape(m, h, hd), 1, 0)
+        wk = jnp.moveaxis(w[:, h * hd:(h + hkv) * hd].reshape(m, hkv, hd),
+                          1, 0)
+        qv, qi = tp_proj_rtopk(x, wq, positions, k=sfa_k,
+                               rope_spec=rope_spec)
+        kv_, ki = tp_proj_rtopk(x, wk, positions, k=sfa_k,
+                                rope_spec=rope_spec)
+        # name the codes as remat="codes" saveables (identity otherwise):
+        # the checkpoint policy saves these (n, k) tensors so the backward
+        # never re-runs the fused projection->rope->top-k pass
+        # (core/remat.py). The k codes are tagged BEFORE the GQA group
+        # repeat — the policy stores them at hkv width and the backward
+        # recomputes the repeat for free.
+        qv, qi = tag_q_codes(qv, qi)
+        kv_, ki = tag_k_codes(kv_, ki)
+        if hkv != h:
+            kv_ = jnp.repeat(kv_, h // hkv, axis=1)
+            ki = jnp.repeat(ki, h // hkv, axis=1)
+        return (qv.reshape(b * h, n, sfa_k), qi.reshape(b * h, n, sfa_k),
+                kv_.reshape(b * h, n, sfa_k), ki.reshape(b * h, n, sfa_k))
 
 
 def _sfa_pallas_fwd(q, k, v, sfa_k, causal, scale, return_residuals=False):

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import scopes
 from repro.kernels._compat import resolve_interpret
 from repro.kernels.flash_sfa import row_to_column
 
@@ -110,6 +111,7 @@ def _rtopk_kernel(x_ref, vals_ref, idx_ref, *, k: int):
     idx_ref[...] = idx
 
 
+@jax.named_scope(scopes.PROJ_RTOPK)
 @functools.partial(jax.jit, static_argnames=("k", "block_rows", "interpret"))
 def rtopk(x: jax.Array, k: int, *, block_rows: int = 256,
           interpret: bool | None = None):
@@ -202,6 +204,7 @@ def _proj_rtopk_kernel(x_ref, w_ref, *rest, k: int, rope_spec):
     idx_ref[0, 0] = idx
 
 
+@jax.named_scope(scopes.PROJ_RTOPK)
 @functools.partial(jax.jit, static_argnames=("k", "rope_spec", "block_n",
                                              "interpret"))
 def proj_rtopk(x: jax.Array, w_heads: jax.Array, positions=None, *, k: int,

@@ -138,6 +138,10 @@ def main():
         print(f"{steps} engine ticks, {total} tokens, "
               f"{eng.num_pages - 1} pool pages x {eng.ecfg.page_size} tok, "
               f"final page utilization {eng.page_utilization():.2f}")
+        c = eng.counters
+        print(f"engine counters: {c.chunk_ticks} chunk ticks, "
+              f"{c.decode_ticks} decode ticks, {c.preemptions} preemptions, "
+              f"peak {c.pages_in_use_peak} pages in use")
         if args.speculative:
             s = eng.spec_stats
             print(f"speculative: draft_len={eng.ecfg.draft_len} "

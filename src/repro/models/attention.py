@@ -46,6 +46,7 @@ import numpy as np
 from repro.configs.base import AttentionConfig, ModelConfig
 from repro.core.attention import chunked_attention
 from repro.core import reports as _ureports
+from repro.core import scopes
 from repro.core.remat import tag_lse
 from repro.core.kv_cache import (
     DenseKV, FeatureMajorKV, KVCache, MLAKV, MLASparseKV, PagedDenseKV,
@@ -361,6 +362,7 @@ _ureports.register_provider("ring", _collect_ring_reports,
                             clear_ring_reports)
 
 
+@jax.named_scope(scopes.ATTN_PROJ)
 def _sfa_proj_attend_fwd_impl(w, x, positions, h, hkv, hd, sfa_k, causal,
                               scale, rope_spec, fwd_fuse=False):
     """Primal: qkv projection [-> rope] -> GQA expand -> ops.py's pallas
@@ -429,6 +431,7 @@ def _sfa_proj_attend_fwd(w, x, positions, h, hkv, hd, sfa_k, causal, scale,
                                      causal, scale, rope_spec, fwd_fuse)
 
 
+@jax.named_scope(scopes.CODE_GRAD)
 def _sfa_proj_attend_bwd(h, hkv, hd, sfa_k, causal, scale, rope_spec,
                          req_emit, fwd_fuse, res, g):
     # fwd_fuse changes only how the residual codes were produced, not their
@@ -484,9 +487,10 @@ def _sfa_proj_attend_bwd(h, hkv, hd, sfa_k, causal, scale, rope_spec,
     wv = w[:, (h + hkv) * hd:]
     dx_q, dwq = sparse_proj_bwd(x_flat, wq_heads, dq_vals, dq_idx, d=hd)
     dx_k, dwk = sparse_proj_bwd(x_flat, wk_heads, dk_vals, dk_idx, d=hd)
-    dv32 = dv_flat.astype(jnp.float32)
-    dx_v = dv32 @ wv.astype(jnp.float32).T
-    dwv = x_flat.astype(jnp.float32).T @ dv32
+    with jax.named_scope(scopes.ATTN_PROJ):
+        dv32 = dv_flat.astype(jnp.float32)
+        dx_v = dv32 @ wv.astype(jnp.float32).T
+        dwv = x_flat.astype(jnp.float32).T @ dv32
     dw = jnp.concatenate(
         [jnp.moveaxis(dwq, 0, 1).reshape(m, h * hd),
          jnp.moveaxis(dwk, 0, 1).reshape(m, hkv * hd), dwv],
@@ -622,6 +626,7 @@ class AttentionOut(NamedTuple):
     distill: jax.Array = jnp.zeros((), jnp.float32)
 
 
+@jax.named_scope(scopes.ATTN_PROJ)
 def attention_apply(params, x, *, cfg: ModelConfig, positions=None,
                     window=None, mode: str = "train", cache=None,
                     cache_len=None, slot=None) -> AttentionOut:

@@ -10,6 +10,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core import scopes
 from repro.distributed.shard import run_tp
 from repro.kernels.code_grad import code_grad_dw, code_grad_dx
 
@@ -30,6 +31,7 @@ def dense(params, x, dtype=None):
     return x @ w
 
 
+@jax.named_scope(scopes.CODE_GRAD)
 def sparse_proj_bwd(x, w_heads, g_vals, g_idx, *, d: int,
                     interpret: bool | None = None):
     """Backward of a head-blocked projection ``y_h = x @ w_h`` whose upstream
@@ -71,6 +73,7 @@ def norm_init(dim: int, kind: str = "rmsnorm"):
     return p
 
 
+@jax.named_scope(scopes.NORM)
 def apply_norm(params, x, kind: str = "rmsnorm", eps: float = 1e-6):
     """Norms with f32 *statistics* but activation-dtype elementwise math.
 
@@ -107,6 +110,7 @@ def mlp_init(rng, d_model: int, d_ff: int, *, glu: bool = True):
             "down": dense_init(r2, d_ff, d_model)}
 
 
+@jax.named_scope(scopes.MLP)
 def mlp(params, x, *, act: str = "silu", glu: bool = True):
     dt = x.dtype
     if glu:
@@ -122,6 +126,7 @@ def embed_init(rng, vocab: int, d_model: int):
     return {"w": jax.random.normal(rng, (vocab, d_model), jnp.float32) * 0.02}
 
 
+@jax.named_scope(scopes.EMBED)
 def embed(params, tokens, dtype=jnp.bfloat16):
     return params["w"].astype(dtype)[tokens]
 
@@ -185,6 +190,7 @@ def rope_code_vjp(vals: jax.Array, idx: jax.Array, positions: jax.Array, *,
     return jnp.concatenate([de, do], axis=-1).astype(vals.dtype)
 
 
+@jax.named_scope(scopes.LOSS)
 def chunked_cross_entropy(hidden: jax.Array, emb_w: jax.Array,
                           labels: jax.Array, *, chunk: int = 512,
                           mask: jax.Array | None = None):

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.core import scopes
 from repro.core.remat import checkpoint_policy, normalize_remat, record_remat
 from repro.distributed.sharding import constrain
 from repro.models import attention as attn
@@ -155,7 +156,8 @@ def _tx_block(p, x, cfg: ModelConfig, kind: str, *, window=None, positions=None,
     ao = attn.attention_apply(p["attn"], h, cfg=cfg, positions=positions,
                               window=window, mode=mode, cache=cache,
                               cache_len=cache_len, slot=slot)
-    x = x + ao.out
+    with jax.named_scope(scopes.ATTN_PROJ):
+        x = x + ao.out
     h = L.apply_norm(p["ln2"], x, cfg.norm)
     if kind == "block_moe":
         mo, aux = moe_lib.moe_apply(p["moe"], h, cfg.moe, act=cfg.act,
@@ -165,7 +167,8 @@ def _tx_block(p, x, cfg: ModelConfig, kind: str, *, window=None, positions=None,
         mo = L.mlp(p["mlp"], h, act=cfg.act, glu=cfg.glu)
         aux = jnp.zeros((), jnp.float32)
     aux = aux + cfg.sfa_distill * ao.distill          # paper Eq. 8 term
-    x = constrain(x + mo, ("batch", None, "embed"))
+    with jax.named_scope(scopes.MLP):
+        x = constrain(x + mo, ("batch", None, "embed"))
     return x, ao.cache, aux
 
 
@@ -299,6 +302,7 @@ def _apply_stack(params, x, cfg: ModelConfig, *, positions, mode,
 # embedding / head
 # ==========================================================================
 
+@jax.named_scope(scopes.EMBED)
 def _embed_inputs(params, batch, cfg: ModelConfig, dtype):
     """Returns (hidden (b, n, d), label_mask or None)."""
     if cfg.family == "audio":
@@ -320,6 +324,26 @@ def _head_weights(params, cfg: ModelConfig):
     if cfg.tie_embeddings:
         return params["embed"]["w"]
     return params["lm_head"]["w"].T      # (vocab, d)
+
+
+@jax.named_scope(scopes.LOSS)
+def _logits(h, params, cfg: ModelConfig):
+    """f32 logits of the final hidden states ``h (..., d)``."""
+    return h.astype(jnp.float32) @ _head_weights(params, cfg).T.astype(jnp.float32)
+
+
+@jax.named_scope(scopes.EMBED)
+def _embed_at(params, tokens, positions, cfg: ModelConfig, dtype):
+    """Embeddings of ``tokens (b, c)`` at ``positions (b, c)`` for the
+    serving steps. A position past the learned table reads its last row
+    (``mode="clip"``), so a decode step and a chunk or verify pass that
+    reach the same position read the same row."""
+    h = L.embed(params["embed"], tokens, dtype) * (
+        cfg.d_model ** 0.5 if cfg.norm == "rmsnorm" else 1.0)
+    if cfg.pos_embedding == "learned":
+        h = h + jnp.take(params["pos"]["w"], positions, axis=0,
+                         mode="clip").astype(dtype)
+    return h
 
 
 # ==========================================================================
@@ -361,8 +385,7 @@ def forward_logits(params, batch, cfg: ModelConfig, *, mode="train"):
     h, aux, caches = _apply_stack(params, h, cfg, positions=positions,
                                   mode=mode)
     h = L.apply_norm(params["final_norm"], h, cfg.norm)
-    logits = h.astype(jnp.float32) @ _head_weights(params, cfg).T.astype(jnp.float32)
-    return ForwardOut(None, logits, caches, aux)
+    return ForwardOut(None, _logits(h, params, cfg), caches, aux)
 
 
 def prefill(params, batch, cfg: ModelConfig):
@@ -374,25 +397,20 @@ def prefill(params, batch, cfg: ModelConfig):
     h, _, caches = _apply_stack(params, h, cfg, positions=positions,
                                 mode="prefill")
     h = L.apply_norm(params["final_norm"], h[:, -1:], cfg.norm)
-    logits = h.astype(jnp.float32) @ _head_weights(params, cfg).T.astype(jnp.float32)
-    return logits[:, 0], caches
+    return _logits(h[:, 0], params, cfg), caches
 
 
 def decode_step(params, token, caches, cache_len, cfg: ModelConfig):
     """One decode step. token: (b,) int32; cache_len: (b,) int32 — number of
     tokens already in the cache. Returns (logits (b, vocab), new caches)."""
     dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
-    h = L.embed(params["embed"], token[:, None], dtype) * (
-        cfg.d_model ** 0.5 if cfg.norm == "rmsnorm" else 1.0)
-    if cfg.pos_embedding == "learned":
-        h = h + params["pos"]["w"].astype(dtype)[cache_len][:, None]
     positions = cache_len[:, None]
+    h = _embed_at(params, token[:, None], positions, cfg, dtype)
     h, _, new_caches = _apply_stack(params, h, cfg, positions=positions,
                                     mode="decode", caches=caches,
                                     cache_len=cache_len)
     h = L.apply_norm(params["final_norm"], h, cfg.norm)
-    logits = h[:, 0].astype(jnp.float32) @ _head_weights(params, cfg).T.astype(jnp.float32)
-    return logits, new_caches
+    return _logits(h[:, 0], params, cfg), new_caches
 
 
 def prefill_chunk(params, tokens, caches, offset, valid, slot, cfg: ModelConfig):
@@ -406,22 +424,14 @@ def prefill_chunk(params, tokens, caches, offset, valid, slot, cfg: ModelConfig)
     ``attention_apply`` mode="chunk"), so interleaving chunks with decode
     steps never changes which cache prefix a query sees."""
     dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
-    c = tokens.shape[1]
-    h = L.embed(params["embed"], tokens, dtype) * (
-        cfg.d_model ** 0.5 if cfg.norm == "rmsnorm" else 1.0)
-    positions = offset + jnp.arange(c)[None, :]
-    if cfg.pos_embedding == "learned":
-        # mode="clip": decode_step's bracket indexing clamps past the table
-        # (jnp.take would fill NaN), and chunk/verify must match it exactly
-        h = h + jnp.take(params["pos"]["w"], positions[0], axis=0,
-                         mode="clip").astype(dtype)[None]
+    positions = offset + jnp.arange(tokens.shape[1])[None, :]
+    h = _embed_at(params, tokens, positions, cfg, dtype)
     h, _, new_caches = _apply_stack(params, h, cfg, positions=positions,
                                     mode="chunk", caches=caches,
                                     cache_len=offset, slot=slot)
     h = L.apply_norm(params["final_norm"], h, cfg.norm)
     hv = jax.lax.dynamic_index_in_dim(h[0], valid - 1, 0, keepdims=False)
-    logits = hv.astype(jnp.float32) @ _head_weights(params, cfg).T.astype(jnp.float32)
-    return logits, new_caches
+    return _logits(hv, params, cfg), new_caches
 
 
 def verify_step(params, tokens, caches, offset, slot, cfg: ModelConfig):
@@ -437,20 +447,13 @@ def verify_step(params, tokens, caches, offset, slot, cfg: ModelConfig):
     ``argmax(logits[j])``. The chunk write lands FULL-k codes at all C
     positions, overwriting whatever the low-k' draft pass wrote there."""
     dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
-    c = tokens.shape[1]
-    h = L.embed(params["embed"], tokens, dtype) * (
-        cfg.d_model ** 0.5 if cfg.norm == "rmsnorm" else 1.0)
-    positions = offset + jnp.arange(c)[None, :]
-    if cfg.pos_embedding == "learned":
-        # mode="clip" to match decode_step's clamping bracket indexing
-        h = h + jnp.take(params["pos"]["w"], positions[0], axis=0,
-                         mode="clip").astype(dtype)[None]
+    positions = offset + jnp.arange(tokens.shape[1])[None, :]
+    h = _embed_at(params, tokens, positions, cfg, dtype)
     h, _, new_caches = _apply_stack(params, h, cfg, positions=positions,
                                     mode="verify", caches=caches,
                                     cache_len=offset, slot=slot)
     h = L.apply_norm(params["final_norm"], h, cfg.norm)
-    logits = h[0].astype(jnp.float32) @ _head_weights(params, cfg).T.astype(jnp.float32)
-    return logits, new_caches
+    return _logits(h[0], params, cfg), new_caches
 
 
 def init_decode_caches(cfg: ModelConfig, batch: int, max_len: int,

@@ -13,6 +13,8 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import scopes
+
 
 @dataclasses.dataclass(frozen=True)
 class OptimizerConfig:
@@ -56,6 +58,7 @@ def global_norm(tree):
                         for l in leaves))
 
 
+@jax.named_scope(scopes.OPTIM)
 def clip_by_global_norm(tree, max_norm):
     norm = global_norm(tree)
     scale = jnp.minimum(1.0, max_norm / jnp.maximum(norm, 1e-9))
@@ -73,6 +76,7 @@ def _is_matrix(p):
     return p.ndim >= 2
 
 
+@jax.named_scope(scopes.OPTIM)
 def adamw_update(cfg: OptimizerConfig, grads, state: OptState, params):
     grads, gnorm = clip_by_global_norm(grads, cfg.grad_clip)
     step = state.step + 1
@@ -95,6 +99,7 @@ def adamw_update(cfg: OptimizerConfig, grads, state: OptState, params):
     return new_params, OptState(step, m, v), {"lr": lr, "grad_norm": gnorm}
 
 
+@jax.named_scope(scopes.OPTIM)
 def lion_update(cfg: OptimizerConfig, grads, state: OptState, params):
     grads, gnorm = clip_by_global_norm(grads, cfg.grad_clip)
     step = state.step + 1

@@ -28,6 +28,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core.kv_cache import KVCache, cache_nbytes
@@ -233,6 +234,16 @@ class PagedEngineConfig:
 
 
 @dataclasses.dataclass
+class EngineCounters:
+    """Scheduling events of a paged engine since it was built. The pages
+    in use now are ``PagedDecodeEngine.page_utilization()``."""
+    chunk_ticks: int = 0           # chunked-prefill chunks run
+    decode_ticks: int = 0          # ticks that decoded the live slots
+    preemptions: int = 0           # live requests evicted for their pages
+    pages_in_use_peak: int = 0     # most pool pages held at once
+
+
+@dataclasses.dataclass
 class _PagedRequest:
     rid: int
     prompt: np.ndarray               # tokens to (re)prefill
@@ -256,6 +267,11 @@ class PagedDecodeEngine(_SamplerMixin):
     exhaustion preempts the youngest live request (recompute-on-resume, so
     greedy streams are bit-reproducible). Greedy tokens match the slot
     ``DecodeEngine`` exactly; requests are keyed by rid, not slot.
+
+    Each phase of ``step()`` runs in a profiler span (``engine.admit``,
+    ``engine.prefill``, ``engine.ensure_pages``, ``engine.decode``; the
+    host read of sampled tokens in ``engine.sample``), on the clock of the
+    device trace; ``counters`` counts the scheduling events.
     """
 
     def __init__(self, params, cfg: ModelConfig, ecfg: PagedEngineConfig):
@@ -299,16 +315,23 @@ class PagedDecodeEngine(_SamplerMixin):
         self._rng = jax.random.PRNGKey(ecfg.seed)
         self._prefill, self._decode = _jitted_fns(cfg)
         self._chunk, self._insert = _paged_jitted_fns(cfg)
+        self.counters = EngineCounters()
 
     # ------------------------------------------------------------------
     def cache_bytes(self) -> int:
         """At-rest bytes of the paged pools (block tables included)."""
         return cache_nbytes(self.caches)
 
+    def _pages_in_use(self) -> int:
+        return self.num_pages - 1 - len(self.free_pages)
+
     def page_utilization(self) -> float:
         """Fraction of allocatable pool pages currently holding live data."""
-        usable = self.num_pages - 1
-        return (usable - len(self.free_pages)) / max(usable, 1)
+        return self._pages_in_use() / max(self.num_pages - 1, 1)
+
+    def _note_pages(self):
+        c = self.counters
+        c.pages_in_use_peak = max(c.pages_in_use_peak, self._pages_in_use())
 
     @property
     def busy(self) -> bool:
@@ -372,6 +395,7 @@ class PagedDecodeEngine(_SamplerMixin):
         exact: the requeued prompt replays everything already in the cache
         and ``resume_token`` re-seeds the pending (sampled, unwritten)
         token."""
+        self.counters.preemptions += 1
         rid = int(self.slot_rid[slot])
         req = self._by_rid[rid]
         out = self.outputs[rid]
@@ -405,6 +429,7 @@ class PagedDecodeEngine(_SamplerMixin):
             for j in range(need):
                 self.bt[slot, j] = self.free_pages.pop()
             self._bt_dirty = True
+            self._note_pages()
             self.slot_rid[slot] = req.rid
             self._seq += 1
             self.slot_seq[slot] = self._seq
@@ -438,6 +463,7 @@ class PagedDecodeEngine(_SamplerMixin):
         logits, self.caches = self._chunk(
             self.params, jnp.asarray(chunk[None, :], jnp.int32), self.caches,
             jnp.int32(off), jnp.int32(take), jnp.int32(slot))
+        self.counters.chunk_ticks += 1
         st["off"] = off + take
         if st["off"] >= len(prompt):
             self._inflight = None
@@ -449,7 +475,9 @@ class PagedDecodeEngine(_SamplerMixin):
         the slot engine's fixed admission semantics)."""
         rid = req.rid
         if req.resume_token is None:
-            tok = int(self._sample(logits)[0])
+            first = self._sample(logits)
+            with TraceAnnotation("engine.sample"):
+                tok = int(first[0])
             self.outputs[rid].append(tok)
             self._emitted[rid] = tok
             budget = req.max_new - 1
@@ -495,14 +523,13 @@ class PagedDecodeEngine(_SamplerMixin):
                 if self.bt[slot, pidx] == 0:
                     self.bt[slot, pidx] = self.free_pages.pop()
                     self._bt_dirty = True
+                    self._note_pages()
         # youngest was preempted first; resume in admission order (oldest
         # requeued entry at the very front)
         for req in requeue:
             self.queue.insert(0, req)
 
     def _decode_tick(self) -> dict[int, int]:
-        if not self.live.any():
-            return {}
         live_before = self.live.copy()
         self._push_bt()
         # non-live slots decode at a past-the-table sentinel position: their
@@ -513,10 +540,12 @@ class PagedDecodeEngine(_SamplerMixin):
         logits, self.caches = self._decode(self.params, self.last_token,
                                            self.caches, jnp.asarray(lens))
         toks = self._sample(logits)
+        with TraceAnnotation("engine.sample"):
+            host_toks = np.asarray(toks)
         self.lengths = self.lengths + live_before.astype(np.int32)
         out = {}
         for slot in np.where(live_before)[0]:
-            t = int(toks[slot])
+            t = int(host_toks[slot])
             rid = int(self.slot_rid[slot])
             out[rid] = t
             self.outputs[rid].append(t)
@@ -537,10 +566,17 @@ class PagedDecodeEngine(_SamplerMixin):
         if not self.busy:
             return {}
         self._emitted = {}
-        self._admit()
-        self._prefill_tick()
-        self._ensure_decode_pages()
-        out = self._decode_tick()
+        with TraceAnnotation("engine.admit"):
+            self._admit()
+        with TraceAnnotation("engine.prefill"):
+            self._prefill_tick()
+        with TraceAnnotation("engine.ensure_pages"):
+            self._ensure_decode_pages()
+        out = {}
+        if self.live.any():
+            self.counters.decode_ticks += 1
+            with TraceAnnotation("engine.decode"):
+                out = self._decode_tick()
         return {**self._emitted, **out}
 
     def generate(self, prompt: np.ndarray, max_new_tokens: int = 32) -> list:

@@ -41,6 +41,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.models import decode_step, verify_step
@@ -142,8 +143,6 @@ class SpeculativeDecodeEngine(PagedDecodeEngine):
                 self._bt_dirty = True
 
     def _decode_tick(self) -> dict[int, int]:
-        if not self.live.any():
-            return {}
         live_before = self.live.copy()
         self._push_bt()
         page = self.ecfg.page_size
@@ -160,7 +159,8 @@ class SpeculativeDecodeEngine(PagedDecodeEngine):
             logits, self.caches = self._draft(self.params, cur, self.caches,
                                               jnp.asarray(lens))
             cur = self._sample(logits)
-            drafts[j] = np.asarray(cur)
+            with TraceAnnotation("engine.sample"):
+                drafts[j] = np.asarray(cur)
         out = {}
         self._spec["ticks"] += 1
         new_last = np.asarray(self.last_token).copy()
@@ -171,7 +171,9 @@ class SpeculativeDecodeEngine(PagedDecodeEngine):
             logits, self.caches = self._verify(
                 self.params, jnp.asarray(toks[None, :], jnp.int32),
                 self.caches, jnp.int32(L), jnp.int32(slot))
-            tg = np.asarray(self._sample(logits)).astype(np.int64)  # (C,)
+            targets = self._sample(logits)
+            with TraceAnnotation("engine.sample"):
+                tg = np.asarray(targets).astype(np.int64)       # (C,)
             m = 0
             while m < J and drafts[m, slot] == tg[m]:
                 m += 1

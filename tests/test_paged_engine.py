@@ -147,6 +147,58 @@ def test_queueing_and_preemption_match_solo_runs(sfa_setup, chunk):
     assert (eng.bt == 0).all()
 
 
+def test_preemption_counted(sfa_setup):
+    """A pool of three 8-token pages and two requests: the younger one
+    needs its second page while the older holds the other two, so it is
+    preempted exactly once, and both streams still match their solo
+    runs."""
+    cfg, params = sfa_setup
+    prompts, news = [PROMPT, PROMPT[:7]], [10, 5]
+    solo = [_slot_ref(cfg, params, p, mn) for p, mn in zip(prompts, news)]
+    per = paged_page_bytes(cfg, page_size=8)
+    eng = _paged(cfg, params, max_len=24, mem_budget_bytes=3 * per)
+    assert eng.num_pages - 1 == 3
+    rids = [eng.add_request(p, max_new_tokens=mn)
+            for p, mn in zip(prompts, news)]
+    while eng.busy:
+        eng.step()
+    assert [eng.outputs[r] for r in rids] == solo
+    assert eng.counters.preemptions == 1
+    assert eng.counters.pages_in_use_peak == 3
+
+
+@pytest.mark.parametrize("n", [4, 7, 11])
+def test_chunk_and_decode_ticks_counted(dense_setup, n):
+    """A prompt of n tokens lands in ceil(n / chunk) chunks; a lone
+    request decodes on every tick after its prefill until its budget."""
+    cfg, params = dense_setup
+    eng = _paged(cfg, params, prefill_chunk=4)
+    eng.generate(PROMPT[:n], max_new_tokens=5)
+    assert eng.counters.chunk_ticks == -(-n // 4)
+    assert eng.counters.decode_ticks == 5 - 1
+    assert eng.counters.preemptions == 0
+    # the four decodes write positions n .. n + 3
+    assert eng.counters.pages_in_use_peak == -(-(n + 4) // 8)
+
+
+def test_engine_phases_are_profiler_spans(dense_setup, tmp_path):
+    """Each phase of a tick is a span in the profiler's trace, on the
+    device trace's clock."""
+    cfg, params = dense_setup
+    eng = _paged(cfg, params, prefill_chunk=4)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.generate(PROMPT, max_new_tokens=3)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    names = {e.name for plane in data.planes for line in plane.lines
+             for e in line.events if e.name.startswith("engine.")}
+    assert names == {"engine.admit", "engine.prefill", "engine.ensure_pages",
+                     "engine.decode", "engine.sample"}
+
+
 def test_page_accounting_single_request(dense_setup):
     """Pages are allocated on demand (prompt pages up front, decode pages
     as the sequence crosses page boundaries) and all return on free."""
