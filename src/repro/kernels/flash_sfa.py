@@ -12,9 +12,10 @@ VMEM scratch across the sequential kv-block grid axis).
 
 ``block_skip=True`` adds overlap-aware tile scheduling on top (DESIGN.md §2):
 a per-tile feature-occupancy bitmap (the OR of each tile's stored indices,
-masked to value-carrying entries) is built from the codes in one O(nk) XLA
-pre-pass, and a (q-tile, k-tile) *level map* derived from it is handed to the
-kernel as a scalar-prefetch operand:
+masked to value-carrying entries, packed into ceil(d/32) uint32 words) is
+built from the codes in one O(nk) XLA pre-pass by a bitwise-OR reduction, not
+a scatter, and a (q-tile, k-tile) *level map* derived from the AND of the
+words is handed to the kernel as a scalar-prefetch operand:
 
   * level 0 — the tile is causally dead or the q tile is fully padded:
     nothing runs, nothing is fetched.
@@ -221,26 +222,34 @@ def _flash_sfa_skip_kernel(lvl_ref, ft_ref, qv_ref, qi_ref, kv_ref, ki_ref,
 
 
 def _tile_occupancy(vals, idx, d: int, nblocks: int, block: int):
-    """(bh, n, k) codes -> (bh, nblocks, d) 0/1 feature-occupancy bitmap.
+    """(bh, n, k) codes -> (bh, nblocks, ceil(d/32)) uint32 occupancy words.
 
-    One f32 lane per feature (the d-bit OR of DESIGN.md §2, kept unpacked so
-    the tile-pair intersection is one MXU matmul). Entries with value 0 are
-    excluded: they contribute nothing to any score, and that is exactly what
-    keeps padded rows (idx=0 × k, val=0) from pinning feature 0 occupied.
+    The d-bit OR of DESIGN.md §2b, packed: feature u of a tile is bit
+    ``u & 31`` of word ``u >> 5``, set iff some entry of the tile's
+    ``block · k`` stores index u with a nonzero value. Entries with value 0
+    are excluded: they contribute nothing to any score, and that is exactly
+    what keeps padded rows (idx=0 × k, val=0) from pinning feature 0
+    occupied.
+
+    Built as one OR reduction per word over the tile's (block, k) entries,
+    never a scatter: a TPU runs an XLA scatter as a serial loop over its
+    updates (~9 ns each, 1.6M of them per call at batch 4 × 4096), where
+    the reductions fuse with the per-entry bit computation and read the
+    codes once, with no intermediate the size of the codes.
     """
-    bh, n, kq = idx.shape
-    flat_idx = idx.reshape(bh, nblocks, block * kq)
-    live = (vals.reshape(bh, nblocks, block * kq) != 0).astype(jnp.float32)
-    # Scatter-max, NOT one_hot: the one-hot form materializes a
-    # (bh, nblocks, block·k, d) f32 intermediate — O(n·k·d) bytes, 400MB+ at
-    # (bh=24, n=2048, d=128) — which dwarfs the codes themselves and used to
-    # set the whole train step's peak memory. The scatter touches only the
-    # (bh, nblocks, block·k) updates and the (bh, nblocks, d) output,
-    # keeping the pre-pass at the O(n·k) bytes the module docstring promises.
-    occ = jnp.zeros((bh, nblocks, d), jnp.float32)
-    return occ.at[jnp.arange(bh)[:, None, None],
-                  jnp.arange(nblocks)[None, :, None],
-                  flat_idx].max(live, mode="drop")
+    bh, _, kq = idx.shape
+    # Split n only: merging block and k into one axis is a relayout of the
+    # codes (k is their minor axis), which XLA will not fuse into a reduce,
+    # so the bits would be written out and read back.
+    idx = idx.reshape(bh, nblocks, block, kq)
+    live = vals.reshape(bh, nblocks, block, kq) != 0
+    bit = jnp.where(live, jnp.left_shift(
+        jnp.uint32(1), (idx & 31).astype(jnp.uint32)), jnp.uint32(0))
+    word = jnp.right_shift(idx, 5)
+    return jnp.stack([
+        jax.lax.reduce(jnp.where(word == w, bit, jnp.uint32(0)),
+                       jnp.uint32(0), jax.lax.bitwise_or, (2, 3))
+        for w in range(pl.cdiv(d, 32))], axis=-1)
 
 
 @jax.named_scope(scopes.BLOCK_MAPS)
@@ -258,7 +267,8 @@ def _block_maps(q_vals, q_idx, k_vals, k_idx, *, d: int, causal: bool,
     nqb, nkb = nqp // block_q, nkp // block_k
     occ_q = _tile_occupancy(q_vals, q_idx, d, nqb, block_q)
     occ_k = _tile_occupancy(k_vals, k_idx, d, nkb, block_k)
-    overlap = jnp.einsum("bqd,bkd->bqk", occ_q, occ_k) > 0.5
+    overlap = jnp.any((occ_q[:, :, None, :] & occ_k[:, None, :, :]) != 0,
+                      axis=-1)                     # (bh, nqb, nkb)
     qs = jnp.arange(nqb)[:, None] * block_q                # (nqb, 1)
     ks = jnp.arange(nkb)[None, :] * block_k                # (1, nkb)
     dead = jnp.broadcast_to(qs >= nq_real, (nqb, nkb))

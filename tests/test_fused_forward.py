@@ -10,6 +10,7 @@ form; the seam-level fused == unfused parity (outputs AND gradients — the
 residual tuple is identical by construction); and the ``CompactSeamReport``
 ``fused_fwd`` field.
 """
+import functools
 import inspect
 
 import jax
@@ -211,6 +212,109 @@ def test_block_skip_occupancy_ignores_value_zero_entries(rng):
                     block_k=64, block_skip=True)
     ref = REF.flash_sfa_ref(qv, qi, kv_, ki, v, d=d, causal=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=ATOL)
+
+
+# --------------------------------------------------------------------------
+# block-skip pre-pass: packed OR words == the scatter-max + einsum formula
+# --------------------------------------------------------------------------
+
+def _scatter_block_maps(q_vals, q_idx, k_vals, k_idx, *, d, causal, block_q,
+                        block_k, nq_real, nk_real):
+    """Oracle: the unpacked f32 occupancy built by a scatter-max and
+    intersected by an einsum, with the level/fetch rule of ``_block_maps``."""
+    def occupancy(vals, idx, block):
+        bh, n, kq = idx.shape
+        nb = n // block
+        live = (vals.reshape(bh, nb, block * kq) != 0).astype(jnp.float32)
+        return jnp.zeros((bh, nb, d), jnp.float32).at[
+            jnp.arange(bh)[:, None, None], jnp.arange(nb)[None, :, None],
+            idx.reshape(bh, nb, block * kq)].max(live, mode="drop")
+
+    occ_q = occupancy(q_vals, q_idx, block_q)
+    occ_k = occupancy(k_vals, k_idx, block_k)
+    overlap = jnp.einsum("bqd,bkd->bqk", occ_q, occ_k) > 0.5
+    nqb, nkb = occ_q.shape[1], occ_k.shape[1]
+    qs = jnp.arange(nqb)[:, None] * block_q
+    ks = jnp.arange(nkb)[None, :] * block_k
+    dead = jnp.broadcast_to(qs >= nq_real, (nqb, nkb))
+    full = ks + block_k <= nk_real
+    if causal:
+        dead = dead | (ks > qs + block_q - 1)
+        full = full & (ks + block_k - 1 <= qs)
+    level = jnp.where(dead[None], 0,
+                      jnp.where(full[None] & ~overlap, 1, 2)).astype(jnp.int32)
+    jidx = jnp.where(level == 2, jnp.arange(nkb)[None, None, :], -1)
+    fetch = jnp.maximum(jax.lax.cummax(jidx, axis=2), 0).astype(jnp.int32)
+    return level, fetch
+
+
+def _edge_codes(seed, bh, n, d, k, block):
+    """Clustered codes: every row of a tile stores the tile's two features,
+    one from the word edges (0, 31, 32, 63, ...) and one uniform over d,
+    with nonzero values; the other k-2 slots hold random indices with value
+    0, which the occupancy must ignore. Two tiles overlap iff they share a
+    feature, so a lost, misplaced or aliased bit flips a level."""
+    r = np.random.default_rng(seed)
+    edges = np.array([e for e in (0, 31, 32, 63, 64, 95, 96, 127) if e < d])
+    nb = -(-n // block)
+    feats = np.stack([r.choice(edges, size=(bh, nb)),
+                      r.integers(0, d, size=(bh, nb))], axis=-1)
+    idx = r.integers(0, d, size=(bh, n, k)).astype(np.int32)
+    idx[..., :2] = np.repeat(feats, block, axis=1)[:, :n]
+    vals = np.zeros((bh, n, k), np.float32)
+    vals[..., :2] = r.choice([-1.0, 1.0], size=(bh, n, 2))
+    return jnp.asarray(vals), jnp.asarray(idx)
+
+
+@pytest.mark.parametrize("codes", ["random", "disjoint", "edges"])
+@pytest.mark.parametrize("nq,nk", [(256, 256), (200, 136)])
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("d", [64, 128])
+def test_block_maps_match_scatter_oracle(rng, codes, nq, nk, causal, d):
+    """Level and fetch maps equal the scatter-max formula's, integer for
+    integer: ragged nq/nk padded as ``flash_sfa`` pads them, value-0 rows
+    in mid-tile, and codes where level 1 fires."""
+    from repro.kernels.flash_sfa import _block_maps, _pad_codes
+    bh, k, block = 3, d // 8, 64
+    key = jax.random.fold_in(rng, 7 * d + nq)
+    if codes == "random":
+        qv, qi = REF.rtopk_ref(jax.random.normal(key, (bh, nq, d)), k)
+        kv_, ki = REF.rtopk_ref(
+            jax.random.normal(jax.random.fold_in(key, 1), (bh, nk, d)), k)
+    elif codes == "disjoint":
+        qv, qi, _, _ = _disjoint_codes(key, bh, nq, d, k)
+        _, _, kv_, ki = _disjoint_codes(key, bh, nk, d, k)
+    else:
+        qv, qi = _edge_codes(nq + d, bh, nq, d, k, block)
+        kv_, ki = _edge_codes(nk + d + 1, bh, nk, d, k, block)
+    # canonical padded rows (idx=0 × k, val=0) in the middle of a tile
+    qv, qi = qv.at[:, 10:20].set(0), qi.at[:, 10:20].set(0)
+    kv_, ki = kv_.at[:, 70:90].set(0), ki.at[:, 70:90].set(0)
+    padded = _pad_codes(qv, qi, kv_, ki, None, block, block)[:4]
+    kw = dict(d=d, causal=causal, block_q=block, block_k=block, nq_real=nq,
+              nk_real=nk)
+    level, fetch = _block_maps(*padded, **kw)
+    want_level, want_fetch = _scatter_block_maps(*padded, **kw)
+    np.testing.assert_array_equal(np.asarray(level), np.asarray(want_level))
+    np.testing.assert_array_equal(np.asarray(fetch), np.asarray(want_fetch))
+    if codes != "random":
+        assert (np.asarray(level) == 1).any(), "level 1 never fired"
+        assert (np.asarray(level) == 2).any() or codes == "disjoint"
+
+
+def test_block_maps_never_scatter():
+    """Grep-able regression (same idiom as the code_grad no-scatter ban):
+    the pre-pass, lowered at gpt2s-train-4k's shape (bh 48, n 4096, k 8,
+    d 64, block 128), holds no scatter, which a TPU runs as a serial loop
+    over its 1.6M updates."""
+    from repro.kernels.flash_sfa import _block_maps
+    codes = [jax.ShapeDtypeStruct((48, 4096, 8), t)
+             for t in (jnp.bfloat16, jnp.int32, jnp.bfloat16, jnp.int32)]
+    maps = functools.partial(_block_maps, d=64, causal=True, block_q=128,
+                             block_k=128, nq_real=4096, nk_real=4096)
+    text = jax.jit(maps).lower(*codes).as_text()
+    assert "reduce" in text
+    assert "scatter" not in text, "the block-skip pre-pass scatters again"
 
 
 # --------------------------------------------------------------------------
