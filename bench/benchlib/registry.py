@@ -1,8 +1,11 @@
 """Finds a cell's files by the names in ``BENCHMARK.json``.
 
-``configs/<config>.json``, ``traffic/<traffic>.json``,
-``reference/<reference>.py`` and ``metrics/<metric>.py``: a later change
-adds a cell or a metric by adding files and entries, never by editing one.
+``configs/<config>.json`` with its weight layout beside it
+(``configs/<config>.layout.json``, written by ``tools/layout.py``),
+``traffic/<traffic>.json``, ``reference/<reference>.py``,
+``metrics/<metric>.py`` and the operations ``ops/<op>.py``: a later change
+adds a configuration, a cell, a metric or an operation by adding files and
+entries, never by editing one.
 """
 from __future__ import annotations
 
@@ -56,6 +59,19 @@ def load_module(path: Path, name: str):
     return mod
 
 
+def layout_file(config: Path) -> Path:
+    """Where the weight layout of the configuration in ``config`` lies."""
+    return config.with_name(config.stem + ".layout.json")
+
+
+def layout(config: Path) -> list:
+    """The weight leaves of the configuration in ``config``."""
+    path = layout_file(config)
+    if not path.exists():
+        raise BenchError(f"{path} is missing: bench/tools/layout.py writes it")
+    return load_json(path)["leaves"]
+
+
 def reference(name: str):
     return load_module(BENCH / "reference" / f"{name}.py", f"ref_{name}")
 
@@ -65,11 +81,25 @@ def metric_reader(name: str):
                        "metric_" + name.replace(".", "_").replace("-", "_"))
 
 
+def ops() -> dict:
+    """Every operation of ``ops/``: name -> module with ``NAMES`` (the
+    trace names of its kernels, regular expressions) and ``work(cfg,
+    mix)`` (its ``work.Work`` in one step, or None)."""
+    return {p.stem: load_module(p, "op_" + p.stem)
+            for p in sorted((BENCH / "ops").glob("*.py"))}
+
+
+def kernel_map() -> dict:
+    """Operation -> the trace names of its kernels."""
+    return {name: list(op.NAMES) for name, op in ops().items()}
+
+
 @dataclasses.dataclass
 class Cell:
     name: str
     chips: int
     config: dict
+    layout: list         # the weight leaves (``tools/layout.py``)
     traffic: dict
     end_to_end: list
     per_layer: list
@@ -86,34 +116,78 @@ def cell(name: str) -> Cell:
     def applies(metric):
         return name in metric.get("workloads", [name])
 
+    path = config_file(bench, w["config"])
     return Cell(
-        name=name, chips=int(w["chips"]),
-        config=load_json(config_file(bench, w["config"])),
+        name=name, chips=int(w["chips"]), config=load_json(path),
+        layout=layout(path),
         traffic=load_json(traffic_file(w["traffic"])),
         end_to_end=[m for m in bench["end_to_end"] if applies(m)],
         per_layer=[m for m in bench["per_layer"] if applies(m)])
 
 
+# keys of a ``model`` block that describe the configuration without naming
+# a field of the system's config: the preset it starts from, the norm
+# epsilon it states (``check_norm_eps``) and the rows of its position table
+DESCRIPTIVE = ("arch", "norm_eps", "pos_rows")
+
+
 def model_config(model: dict):
-    """The system's ``ModelConfig`` for a configuration's ``model`` block."""
+    """The system's ``ModelConfig`` for a configuration's ``model`` block.
+
+    The block's ``arch`` preset is the start. Every other key names a field
+    of ``ModelConfig`` or of ``AttentionConfig`` (both, where both have it)
+    and sets it; a nested block (``mla``, ``moe``) becomes the field's own
+    config class, field by field. ``window`` and ``sfa_rope_protect`` are
+    None and 0 unless the block states them. A key that names no field and
+    is not one of ``DESCRIPTIVE`` cannot be run as stated."""
     from repro.configs import get_config
 
     check_norm_eps(model)
     base = get_config(model["arch"])
-    att = dataclasses.replace(
-        base.attention, num_heads=model["num_heads"],
-        num_kv_heads=model["num_kv_heads"], head_dim=model["head_dim"],
-        sfa_k=model["sfa_k"], rope=model["rope"],
-        rope_theta=float(model.get("rope_theta", base.attention.rope_theta)),
-        qk_norm=model["qk_norm"], window=None, sfa_rope_protect=0)
-    return dataclasses.replace(
-        base, num_layers=model["num_layers"], d_model=model["d_model"],
-        d_ff=model["d_ff"], vocab_size=model["vocab_size"],
-        max_seq_len=model["max_seq_len"], norm=model["norm"],
-        act=model["act"], glu=model["glu"],
-        tie_embeddings=model["tie_embeddings"],
-        pos_embedding=model["pos_embedding"], dtype=model["dtype"],
-        attention=att)
+    top: dict = {}
+    att: dict = {"window": None, "sfa_rope_protect": 0}
+    for key, value in model.items():
+        if key in DESCRIPTIVE:
+            continue
+        named = False
+        for obj, out in ((base, top), (base.attention, att)):
+            if obj is not None and key in _field_names(obj):
+                out[key] = _field_value(obj, key, value)
+                named = True
+        if not named:
+            raise BenchError(f"model key {key!r} names no field of the "
+                             f"system's ModelConfig or AttentionConfig")
+    attention = top.pop("attention", base.attention)
+    if attention is not None:
+        top["attention"] = dataclasses.replace(attention, **att)
+    return dataclasses.replace(base, **top)
+
+
+def _field_names(obj) -> set:
+    return {f.name for f in dataclasses.fields(obj)}
+
+
+def _field_value(obj, key: str, value):
+    """``value`` for the field ``key`` of ``obj``: a nested block becomes the
+    config class that the field holds, from the field's value where it has
+    one, else from the class's defaults."""
+    if not isinstance(value, dict):
+        return value
+    import typing
+
+    hint = typing.get_type_hints(type(obj))[key]
+    (cls,) = [t for t in (hint, *typing.get_args(hint))
+              if dataclasses.is_dataclass(t)]
+    unknown = set(value) - _field_names(cls)
+    if unknown:
+        raise BenchError(f"model key {key!r}: {sorted(unknown)} name no "
+                         f"field of {cls.__name__}")
+    current = getattr(obj, key)
+    try:
+        return (dataclasses.replace(current, **value) if current is not None
+                else cls(**value))
+    except TypeError as e:
+        raise BenchError(f"model key {key!r}: {e}") from None
 
 
 def check_layout(cfg, params) -> None:

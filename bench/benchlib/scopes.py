@@ -8,7 +8,7 @@ one ``[scope, recomputed]`` pair for each device event, aligned with
 ``devices``. So ``trace.reduce`` reads the same record, and its idle gaps
 name engine phases too.
 
-An event's scope is the innermost name of ``SCOPES`` in its op's name
+An event's scope is the innermost name of the vocabulary in its op's name
 stack: the ``tf_op`` stat that the TPU profiler gives each op. XLA leaves
 some fusions with the name stack of the loop they sit in; for those the
 ops fused inside, in the HLO module that the profiler keeps in its
@@ -25,6 +25,7 @@ recomputed part, as the mean over devices.
 from __future__ import annotations
 
 import collections
+import functools
 import glob
 import os
 import re
@@ -32,9 +33,10 @@ from dataclasses import dataclass, field
 
 from benchlib import registry, trace
 
-# the program's names (src/repro/core/scopes.py; bench/tests pins them
-# equal), kept here too because the benchmark also reads programs that
-# predate that module
+# the program's names (src/repro/core/scopes.py), kept here too because
+# the benchmark also reads programs that predate that module; a
+# configuration adds its own (``Vocabulary.of``), and bench/tests pins the
+# union equal to the program's
 SCOPES = ("embed", "norm", "attn.proj", "sfa.proj_rtopk", "sfa.block_maps",
           "sfa.fwd", "sfa.bwd", "sfa.code_grad", "mlp", "loss", "optim")
 FORWARD = ("embed", "norm", "attn.proj", "sfa.proj_rtopk", "sfa.block_maps",
@@ -44,25 +46,61 @@ ENGINE_SPAN = "engine."
 # where run.py's Context writes the trace of a --trace 1 run
 TRACE_DIR = registry.ROOT / ".bench_trace"
 
-# a scope is a whole component of the name stack, possibly inside a
-# transformation's parentheses ("jvp(embed)"), never a jitted function's
-# name ("jit(norm)"); the TPU's tf_op ends in ":"
-SCOPE = re.compile(r"(?:^|/|(?<!jit)\()(" + "|".join(map(re.escape, SCOPES))
-                   + r")(?=$|/|\)|:)")
+
+@dataclass(frozen=True)
+class Vocabulary:
+    """The scope names an op is attributed by, and which of them are
+    forward scopes (re-run under ``jax.checkpoint``)."""
+    names: tuple
+    forward: tuple
+
+    @classmethod
+    def of(cls, configs) -> "Vocabulary":
+        """``SCOPES`` and ``FORWARD`` with the names that each
+        configuration file declares (``"scopes": {"names": [...],
+        "forward": [...]}``) added after them."""
+        names, forward = list(SCOPES), list(FORWARD)
+        for config in configs:
+            declared = config.get("scopes", {})
+            new, fwd = declared.get("names", []), declared.get("forward", [])
+            if not set(fwd) <= set(new):
+                raise registry.BenchError(
+                    f"{config.get('name')}: forward scopes "
+                    f"{sorted(set(fwd) - set(new))} are not among its names")
+            names += [x for x in new if x not in names]
+            forward += [x for x in fwd if x not in forward]
+        return cls(tuple(names), tuple(forward))
+
+    @functools.cached_property
+    def pattern(self):
+        # a scope is a whole component of the name stack, possibly inside
+        # a transformation's parentheses ("jvp(embed)"), never a jitted
+        # function's name ("jit(norm)"); the TPU's tf_op ends in ":"
+        return re.compile(r"(?:^|/|(?<!jit)\()("
+                          + "|".join(map(re.escape, self.names))
+                          + r")(?=$|/|\)|:)")
+
+    def attribute(self, name_stack: str):
+        """``(scope, recomputed)`` of an op's name stack; scope None when
+        it carries none of the names."""
+        found = self.pattern.findall(name_stack)
+        if not found:
+            return None, False
+        scope = found[-1]
+        return scope, scope in self.forward and RECOMPUTE in name_stack
 
 
-def attribute(name_stack: str):
-    """``(scope, recomputed)`` of an op's name stack; scope None when it
-    carries none of ``SCOPES``."""
-    found = SCOPE.findall(name_stack)
-    if not found:
-        return None, False
-    scope = found[-1]
-    return scope, scope in FORWARD and RECOMPUTE in name_stack
+def vocabulary() -> Vocabulary:
+    """The scopes of the program and of every configuration that
+    ``BENCHMARK.json`` names."""
+    bench = registry.benchmark()
+    return Vocabulary.of(registry.load_json(registry.ROOT / c["file"])
+                         for c in bench["configs"])
 
 
 def load(trace_dir) -> dict:
-    """The newest trace under ``trace_dir`` as a record (module doc)."""
+    """The newest trace under ``trace_dir`` as a record (module doc),
+    attributed by ``vocabulary()``."""
     import jax
 
     record = trace.load_xplane(str(trace_dir))
@@ -74,7 +112,7 @@ def load(trace_dir) -> dict:
                         for line in plane.lines for e in line.events
                         if e.name.startswith(ENGINE_SPAN)]
     with open(path, "rb") as f:
-        ops = op_scopes(memoryview(f.read()))
+        ops = op_scopes(memoryview(f.read()), vocabulary())
     record["scopes"] = {dev: [list(ops.get(dev, {}).get(e[0], (None, False)))
                               for e in evs]
                         for dev, evs in record["devices"].items()}
@@ -231,9 +269,9 @@ def _plane_metadata(plane):
     return _str(plane[2][0]), metas, stat_names
 
 
-def _hlo_inner_scopes(hlo_proto) -> dict:
-    """Instruction name -> the ``attribute`` of each op fused or called
-    inside it, from one HloProto."""
+def _hlo_inner_scopes(hlo_proto, vocab: Vocabulary) -> dict:
+    """Instruction name -> ``vocab``'s attribution of each op fused or
+    called inside it, from one HloProto."""
     comps = {}
     for comp in _fields(_fields(hlo_proto)[1][0])[3]:
         c = _fields(comp)
@@ -251,25 +289,25 @@ def _hlo_inner_scopes(hlo_proto) -> dict:
                 continue
             seen.add(cid)
             for _, op_name, called in comps[cid]:
-                yield attribute(op_name)
+                yield vocab.attribute(op_name)
                 yield from inner(called, seen)
 
     return {name: [a for a in inner(called, set()) if a[0] is not None]
             for rows in comps.values() for name, _, called in rows if called}
 
 
-def op_scopes(xspace) -> dict:
+def op_scopes(xspace, vocab: Vocabulary) -> dict:
     """Device -> {op name: (scope, recomputed)} from a serialized XSpace:
     each op's ``tf_op`` name stack, else the scope most of the ops inside
-    it carry. An op name that two programs give different scopes is left
-    out."""
+    it carry, both attributed by ``vocab``. An op name that two programs
+    give different scopes is left out."""
     planes = [_plane_metadata(_fields(p)) for p in _fields(xspace)[1]]
     inner: dict = {}
     for _, metas, stat_names in planes:
         for meta in metas:
             proto = _stats(meta, stat_names).get("Hlo Proto")
             if proto is not None:
-                inner.update(_hlo_inner_scopes(proto))
+                inner.update(_hlo_inner_scopes(proto, vocab))
     out = {}
     for pname, metas, stat_names in planes:
         m = trace.DEVICE_PLANE.match(pname)
@@ -280,7 +318,7 @@ def op_scopes(xspace) -> dict:
             if not meta[2]:
                 continue
             name = trace.op_name(_str(meta[2][0]))
-            got = attribute(_stats(meta, stat_names).get("tf_op") or "")
+            got = vocab.attribute(_stats(meta, stat_names).get("tf_op") or "")
             if got[0] is None and inner.get(name):
                 got = collections.Counter(inner[name]).most_common(1)[0][0]
             ops[name] = got if ops.get(name, got) == got else (None, False)

@@ -114,19 +114,24 @@ class Reduction:
 
 def kernel_op(name: str, kernel_map: dict) -> str | None:
     """The operation a device event belongs to, by the kernel-name map
-    (``kernels.json``: operation -> list of name patterns)."""
+    (operation -> list of name patterns; ``registry.kernel_map``, the
+    ``NAMES`` of each ``ops/<op>.py``)."""
     for op, patterns in kernel_map.items():
         if any(re.search(p, name) for p in patterns):
             return op
     return None
 
 
-def reduce(record: dict, kernel_map: dict, *,
+def reduce(record: dict, kernel_map: dict | None = None, *,
            collective=r"collective-permute|all-reduce|all-gather|"
                       r"reduce-scatter|all-to-all",
            top: int = 10) -> Reduction:
     """Reduce a trace record over its window: the ``bench.window`` span
-    when there is one, else the span of all device events."""
+    when there is one, else the span of all device events. Kernels are
+    found by ``kernel_map``, by default every operation of ``ops/``."""
+    if kernel_map is None:
+        from benchlib import registry
+        kernel_map = registry.kernel_map()
     wins = [s for s in record["spans"] if s[0] == WINDOW_SPAN]
     devices = record["devices"]
     if not devices or not any(devices.values()):

@@ -76,17 +76,16 @@ def train(cell, ctx, built=None):
     from repro.distributed.sharding import axis_rules
 
     mix = cell.traffic
-    m = cell.config["model"]
     b, n = int(mix["batch"]), int(mix["seq_len"])
     nsteps = int(mix["check"]["steps"])
     cfg, mesh, make = build_step(cell, ctx.devices)
     counter = CompileCounter()
     with mesh, axis_rules(mesh):
-        params = weights.make(ctx.seed, m)
+        params = weights.make(ctx.seed, cell.layout)
         registry.check_layout(cfg, params)
         step, opt = make(params)
         batches = traffic.markov_batches(ctx.seed, int(mix["batches"]), b, n,
-                                         m["vocab_size"])
+                                         cfg.vocab_size)
         if built is not None and "compiled" in built:
             compiled = built["compiled"]
         else:
@@ -100,7 +99,7 @@ def train(cell, ctx, built=None):
             losses.append(float(met["loss"]))
             if i == 0:
                 grad1 = [float(x) / (1 - b1) for x in _leaf_norms(opt.m)]
-        p0 = weights.make(ctx.seed, m)
+        p0 = weights.make(ctx.seed, cell.layout)
         change = [float(x) for x in _change_norms(params, p0)]
         del p0
         i = nsteps
@@ -147,9 +146,10 @@ def train(cell, ctx, built=None):
     if ctx.trace:
         host["traced_s"] = traced_s
         host["traced_tokens"] = traced_steps * tokens
-        host["model_flops_per_token"] = work.train_flops_per_token(m, n)
-        traced_work = step_work(m, b, n).items()
-        traced_work = {k: w.scaled(traced_steps) for k, w in traced_work}
+        host["model_flops_per_token"] = work.train_flops_per_token(
+            cfg, cell.layout, n)
+        traced_work = {k: w.scaled(traced_steps)
+                       for k, w in step_work(cfg, mix).items()}
     memory = ctx.memory_peak()
     del params, opt, met, compiled, step, batches, pending
     ctx.free()
@@ -158,13 +158,15 @@ def train(cell, ctx, built=None):
             (losses, grad1, change))
 
 
-def step_work(m: dict, b: int, n: int) -> dict:
-    """SFA attention work of one training step, all layers."""
-    geom = dict(bh=b * m["num_heads"], n=n, k=m["sfa_k"], dv=m["head_dim"])
-    emit = 2 * m["sfa_k"] if m["rope"] else m["sfa_k"]
-    layers = m["num_layers"]
-    return {"sfa_fwd": work.sfa_fwd(**geom).scaled(layers),
-            "sfa_bwd": work.sfa_bwd(**geom, emit_k=emit).scaled(layers)}
+def step_work(cfg, mix: dict) -> dict:
+    """Work of one training step of each operation of ``ops/`` that counts
+    its work for this configuration."""
+    out = {}
+    for name, op in registry.ops().items():
+        w = op.work(cfg, mix)
+        if w is not None:
+            out[name] = w
+    return out
 
 
 def reference_readings(cell, seed, mode="f32", steps=None):
@@ -177,8 +179,9 @@ def reference_readings(cell, seed, mode="f32", steps=None):
     steps = int(mix["check"]["steps"]) if steps is None else steps
     b, n = int(mix["batch"]), int(mix["seq_len"])
     batches = traffic.markov_batches(seed, int(mix["batches"]), b, n,
-                                     m["vocab_size"])[:steps]
-    p0 = weights.make(seed, m)
+                                     registry.model_config(m).vocab_size
+                                     )[:steps]
+    p0 = weights.make(seed, cell.layout)
     opt = dict(mix["optimizer"])
     losses, g1, p = ref.train_steps(
         p0, m, [(x["tokens"], x["labels"]) for x in batches], opt,

@@ -1,11 +1,13 @@
 """Random weights from the seed, made on the device in one jitted call.
 
-The tree has the layout the system under test reads (a stacked per-layer
-dict); ``registry.check_layout`` compares it with the program's own init
-shapes before a run. The scales follow the usual fan-in rule: matrices
-N(0, 1/fan_in), the token table N(0, 0.02^2), the position table
-N(0, 0.01^2), norm scales 1 and biases 0. Parameters are float32, as the
-system stores them.
+The tree is the configuration's weight layout (``configs/<config>.layout
+.json``, written from the system's own init by ``tools/layout.py``): each
+leaf's path and shape, in the order the system's tree flattens, which is the
+order in which leaves take their keys. ``registry.check_layout`` compares
+the tree with the program's own init shapes before a run. The scales follow
+the usual fan-in rule: matrices N(0, 1/fan_in), the token table
+N(0, 0.02^2), the position table N(0, 0.01^2), norm scales 1 and biases 0.
+Parameters are float32, as the system stores them.
 """
 from __future__ import annotations
 
@@ -13,37 +15,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-
-def layout(m: dict) -> dict:
-    """Shapes of every leaf, in the system's tree layout."""
-    d, L = m["d_model"], m["num_layers"]
-    h, hkv, hd = m["num_heads"], m["num_kv_heads"], m["head_dim"]
-
-    def norm(dim, lead=()):
-        out = {"scale": lead + (dim,)}
-        if m["norm"] == "layernorm":
-            out["bias"] = lead + (dim,)
-        return out
-
-    attn = {"w_qkv": {"w": (L, d, (h + 2 * hkv) * hd)},
-            "w_o": {"w": (L, h * hd, d)}}
-    if m["qk_norm"]:
-        attn["q_norm"] = {"scale": (L, hd)}
-        attn["k_norm"] = {"scale": (L, hd)}
-    if m["glu"]:
-        mlp = {"up_gate": {"w": (L, d, 2 * m["d_ff"])},
-               "down": {"w": (L, m["d_ff"], d)}}
-    else:
-        mlp = {"up": {"w": (L, d, m["d_ff"])},
-               "down": {"w": (L, m["d_ff"], d)}}
-    tree = {"embed": {"w": (m["vocab_size"], d)},
-            "final_norm": norm(d),
-            "segments": [{"ln1": norm(d, (L,)), "attn": attn,
-                          "ln2": norm(d, (L,)), "mlp": mlp}]}
-    if m["pos_embedding"] == "learned":
-        tree["pos"] = {"w": (m["pos_rows"], d)}
-    return tree
 
 
 def _leaf(key, path: str, shape):
@@ -67,21 +38,36 @@ def seed_key(seed: int):
     return jax.random.fold_in(k, (seed >> 32) & 0xFFFFFFFF)
 
 
+def nest(paths, values):
+    """The tree whose leaf at each path (a tuple of dict keys and list
+    indices) is the value at the same place in ``values``."""
+    root: dict = {}
+    for path, value in zip(paths, values):
+        node = root
+        for k in path[:-1]:
+            node = node.setdefault(k, {})
+        node[path[-1]] = value
+
+    def lists(node):
+        if not isinstance(node, dict):
+            return node
+        if node and all(isinstance(k, int) for k in node):
+            return [lists(node[i]) for i in range(len(node))]
+        return {k: lists(v) for k, v in node.items()}
+
+    return lists(root)
+
+
 @functools.partial(jax.jit, static_argnums=(1,))
-def _make(key, m_items):
-    m = dict(m_items)
-    shapes = layout(m)
-    flat, tree = jax.tree_util.tree_flatten_with_path(
-        shapes, is_leaf=lambda x: isinstance(x, tuple))
-    leaves = []
-    for i, (path, shape) in enumerate(flat):
-        name = "/".join(str(getattr(p, "key", getattr(p, "idx", p)))
-                        for p in path)
-        leaves.append(_leaf(jax.random.fold_in(key, i), name, shape))
-    return jax.tree_util.tree_unflatten(tree, leaves)
+def _make(key, spec):
+    leaves = [_leaf(jax.random.fold_in(key, i), "/".join(map(str, path)),
+                    shape) for i, (path, shape) in enumerate(spec)]
+    return nest([path for path, _ in spec], leaves)
 
 
-def make(seed: int, m: dict):
-    """The weights of ``m`` (a configuration's ``model`` block) for ``seed``."""
-    return _make(jax.random.fold_in(seed_key(seed), 1),
-                 tuple(sorted(m.items())))
+def make(seed: int, layout: list):
+    """The weights of a configuration's ``layout`` (its list of leaves)
+    for ``seed``."""
+    spec = tuple((tuple(leaf["path"]), tuple(leaf["shape"]))
+                 for leaf in layout)
+    return _make(jax.random.fold_in(seed_key(seed), 1), spec)

@@ -4,15 +4,14 @@ Each count is the operation's own work, not one kernel's: no densify pass,
 no padding, no recompute, no cache tile read twice. A later kernel that does
 the same operation with less waste therefore still has a share of at most
 100% against these counts. ``PERF.md`` states each formula and which bound
-(compute or bytes) sets each roofline.
-
-Notation, per head: ``P`` causal (query, key) pairs, ``k`` = ``sfa_k``,
-``dv`` the value width. Byte widths are the dtypes the operation's inputs
-and outputs have on the trained path: code values and V in bfloat16, code
-indices int32, LSE float32.
+(compute or bytes) sets each roofline. The operations' own counts are in
+``ops/<op>.py``; what they share is here, read from the system's
+``ModelConfig`` and the configuration's weight layout.
 """
 from __future__ import annotations
 
+import collections
+import math
 from dataclasses import dataclass
 
 
@@ -36,61 +35,64 @@ class Work:
                 >= self.bytes / peak_bytes_per_s else "bytes")
 
 
-
-def causal_pairs(n: int) -> int:
-    return n * (n + 1) // 2
-
-
-def sfa_fwd(*, bh: int, n: int, k: int, dv: int, val_b: int = 2,
-            idx_b: int = 4, v_b: int = 2, lse_b: int = 4) -> Work:
-    """FlashSFA forward over ``bh`` (batch x head) rows of length ``n``.
-
-    FLOPs = P * 2(k + dv): a k-sparse score, then P.V.
-    Bytes = q and k codes (values and indices) + V + O + LSE, once each.
-    """
-    flops = bh * causal_pairs(n) * 2 * (k + dv)
-    codes = 2 * n * k * (val_b + idx_b)
-    byts = bh * (codes + n * dv * v_b + n * dv * v_b + n * lse_b)
-    return Work(flops, byts)
+def causal_pairs(n: int, window: int | None = None) -> int:
+    """(query, key) pairs of a causal sequence of ``n``; with a window,
+    each query sees its last ``window`` keys at most."""
+    if window is None or window >= n:
+        return n * (n + 1) // 2
+    return window * (window + 1) // 2 + (n - window) * window
 
 
-def sfa_bwd(*, bh: int, n: int, k: int, dv: int, emit_k: int | None = None,
-            val_b: int = 2, idx_b: int = 4, v_b: int = 2, lse_b: int = 4,
-            grad_b: int = 2) -> Work:
-    """FlashSFA backward: FLOPs = P * 4(k + dv).
-
-    Bytes = codes, V, O, dO and LSE read once, plus the dq and dk code
-    gradients (``emit_k`` wide, k unless RoPE widens them to pair
-    closures) and dV written once.
-    """
-    ek = k if emit_k is None else emit_k
-    flops = bh * causal_pairs(n) * 4 * (k + dv)
-    reads = 2 * n * k * (val_b + idx_b) + 3 * n * dv * v_b + n * lse_b
-    writes = 2 * n * ek * grad_b + n * dv * v_b
-    return Work(flops, bh * (reads + writes))
-
-
-def matmul_params(m: dict) -> int:
-    """N_matmul: non-embedding weights plus the tied logits matmul (the
-    position table is a lookup, not a matmul). ``m`` is the configuration
-    file's ``model`` block."""
-    d, hd = m["d_model"], m["head_dim"]
-    h, hkv = m["num_heads"], m["num_kv_heads"]
-    attn = d * (h + 2 * hkv) * hd + h * hd * d
-    mlp = (3 if m["glu"] else 2) * d * m["d_ff"]
-    return m["num_layers"] * (attn + mlp) + m["vocab_size"] * d
+def attention_pairs(cfg, n: int) -> collections.Counter:
+    """Causal pairs per head of each attention layer at length ``n``, as
+    pairs -> number of layers. A hybrid stack has one attention layer in
+    each period; under a local/global pattern every (pattern + 1)-th layer
+    is global."""
+    a = cfg.attention
+    layers = (cfg.num_layers // cfg.hybrid_period if cfg.hybrid_period
+              else cfg.num_layers)
+    pat = a.local_global_pattern
+    return collections.Counter(
+        causal_pairs(n, None if pat is not None and i % (pat + 1) == pat
+                     else a.window)
+        for i in range(layers))
 
 
-def attn_fwd_flops(m: dict, context: float) -> float:
-    """SFA attention forward FLOPs of one token that sees ``context`` keys,
-    over all layers and query heads: context * 2(k + dv) each."""
-    return (m["num_layers"] * m["num_heads"] * context * 2
-            * (m["sfa_k"] + m["head_dim"]))
+def value_width(cfg) -> int:
+    """The width of a head's value: MLA's ``v_head_dim``, else the head's."""
+    a = cfg.attention
+    return a.mla.v_head_dim if a.mla is not None else a.head_dim
 
 
-def train_flops_per_token(m: dict, n: int) -> float:
+def sfa_fwd_flops(cfg, n: int) -> int:
+    """SFA attention forward FLOPs of one sequence of ``n``, over all
+    layers and query heads: P * 2(k + dv) each."""
+    a = cfg.attention
+    pairs = sum(p * count for p, count in attention_pairs(cfg, n).items())
+    return pairs * a.num_heads * 2 * (a.sfa_k + value_width(cfg))
+
+
+def matmul_params(cfg, layout: list) -> float:
+    """N_matmul, the weights a token multiplies by, from the layout's
+    roles: every ``matmul`` leaf; each ``experts`` leaf times ``top_k``
+    over its router's output width (a token visits top_k of the experts
+    the router chooses among, whatever number of them the tree holds);
+    and the tied logits matmul. Lookups and norms are not matmuls."""
+    shapes = {tuple(leaf["path"]): leaf["shape"] for leaf in layout}
+    n = 0.0
+    for leaf in layout:
+        size = math.prod(leaf["shape"])
+        if leaf["role"] == "matmul":
+            n += size
+        elif leaf["role"] == "experts":
+            n += size * cfg.moe.top_k / shapes[tuple(leaf["router"])][-1]
+    if cfg.tie_embeddings:
+        n += cfg.vocab_size * cfg.d_model
+    return n
+
+
+def train_flops_per_token(cfg, layout: list, n: int) -> float:
     """MFU's model FLOPs for training at sequence length ``n``: three
     times the forward (6 * N_matmul, and 3 x the SFA attention forward of
-    the mean token, which sees (n + 1) / 2 keys)."""
-    return 6 * matmul_params(m) + 3 * attn_fwd_flops(m, (n + 1) / 2)
-
+    the mean token)."""
+    return 6 * matmul_params(cfg, layout) + 3 * sfa_fwd_flops(cfg, n) / n

@@ -120,9 +120,7 @@ def metrics_of(cell, ctx, out):
         return metrics, None, None
     from benchlib import trace as tr
 
-    record = tr.load_xplane(str(TRACE_DIR))
-    kernels = registry.load_json(BENCH / "kernels.json")["operations"]
-    red = tr.reduce(record, kernels)
+    red = tr.reduce(tr.load_xplane(str(TRACE_DIR)))
     view = Reading(red, out.get("work") or {}, host, ctx)
     metrics = {}
     for m in cell.per_layer:

@@ -109,3 +109,38 @@ def test_peaks_table():
     peaks = registry.load_json(Path(registry.BENCH) / "peaks.json")
     v5e = peaks["devices"]["TPU v5 lite"]
     assert v5e["bf16_flops"] == 197e12 and v5e["hbm_bytes_per_s"] == 819e9
+
+
+def test_gpt2_model_config_is_unchanged():
+    """The gpt2 block gives the ModelConfig that the fixed key list gave
+    before every key of the block was applied by name."""
+    from repro.configs.base import AttentionConfig, ModelConfig
+    frozen = ModelConfig(
+        name="gpt2-small-sfa8", family="dense", num_layers=12, d_model=768,
+        d_ff=3072, vocab_size=50257,
+        attention=AttentionConfig(
+            num_heads=12, num_kv_heads=12, head_dim=64, sfa_k=8, window=None,
+            local_global_pattern=None, mla=None, rope=False,
+            rope_theta=10000.0, causal=True, qk_norm=False, backend="xla",
+            decode_backend="auto", bwd_emit="dense", fwd_fuse=True,
+            ring=False, sfa_rope_protect=0, sfa_draft_k=None),
+        moe=None, ssm=None, rwkv=None, frontend=None, hybrid_period=None,
+        hybrid_attn_index=None, norm="layernorm", act="gelu", glu=False,
+        tie_embeddings=True, causal=True, pos_embedding="learned",
+        max_seq_len=131072, dtype="bfloat16", remat="full", loss_chunk=512,
+        sfa_distill=0.0)
+    path = registry.config_file(BENCH, "gpt2-small-sfa8")
+    assert registry.model_config(registry.load_json(path)["model"]) == frozen
+
+
+def test_model_config_applies_every_field():
+    """A key sets the field it names; a window and a nested block too, and
+    a key that two config classes share sets both."""
+    path = registry.config_file(BENCH, "gpt2-small-sfa8")
+    m = dict(registry.load_json(path)["model"], window=256, causal=False,
+             loss_chunk=1024, moe={"num_experts": 8, "top_k": 2,
+                                   "expert_dim": 128})
+    cfg = registry.model_config(m)
+    assert cfg.attention.window == 256 and cfg.loss_chunk == 1024
+    assert not cfg.causal and not cfg.attention.causal
+    assert (cfg.moe.num_experts, cfg.moe.top_k, cfg.moe.num_shared) == (8, 2, 0)

@@ -11,7 +11,7 @@ import pytest
 from benchlib import registry, scopes, trace
 
 DATA = Path(__file__).resolve().parent / "data"
-KERNELS = registry.load_json(registry.BENCH / "kernels.json")["operations"]
+KERNELS = registry.kernel_map()
 
 
 @pytest.fixture(scope="module")
@@ -25,9 +25,12 @@ def red(step):
 
 
 def test_vocabulary_is_the_programs():
+    """The names the benchmark attributes by, its own and those that the
+    configurations of BENCHMARK.json declare, are the program's."""
     from repro.core import scopes as program
-    assert scopes.SCOPES == program.SCOPES
-    assert set(scopes.FORWARD) < set(scopes.SCOPES)
+    vocab = scopes.vocabulary()
+    assert vocab.names == program.SCOPES
+    assert set(vocab.forward) < set(vocab.names)
 
 
 @pytest.mark.parametrize("stack, want", [
@@ -46,7 +49,63 @@ def test_vocabulary_is_the_programs():
     ("jit(step)/transpose(jvp())/while:", (None, False)),
 ])
 def test_attribute(stack, want):
-    assert scopes.attribute(stack) == want
+    assert scopes.vocabulary().attribute(stack) == want
+
+
+# the attribution before configurations declared scopes, frozen
+OLD_SCOPE = re.compile(r"(?:^|/|(?<!jit)\()(" + "|".join(map(re.escape, (
+    "embed", "norm", "attn.proj", "sfa.proj_rtopk", "sfa.block_maps",
+    "sfa.fwd", "sfa.bwd", "sfa.code_grad", "mlp", "loss", "optim")))
+    + r")(?=$|/|\)|:)")
+OLD_FORWARD = ("embed", "norm", "attn.proj", "sfa.proj_rtopk",
+               "sfa.block_maps", "sfa.fwd", "mlp", "loss")
+
+
+def old_attribute(stack):
+    found = OLD_SCOPE.findall(stack)
+    if not found:
+        return None, False
+    return found[-1], (found[-1] in OLD_FORWARD
+                       and "rematted_computation" in stack)
+
+
+def test_attribution_is_unchanged(step, red):
+    """Every scope, alone and inside another, in the forward pass, its
+    transpose and its recompute, is attributed as before; and the recorded
+    step reads the same seconds by scope."""
+    from repro.core import scopes as program
+    stacks = []
+    for outer in ("", "attn.proj/", "mlp/"):
+        for s in program.SCOPES + ("sfa", "fwd", "mlpx", "jit(mlp)"):
+            stacks += [f"jit(step)/jvp()/while/body/{outer}{s}/dot:",
+                       f"jit(step)/transpose(jvp())/while/body/checkpoint/"
+                       f"rematted_computation/{outer}{s}/pallas_call",
+                       f"jit(step)/transpose(jvp({outer}{s}))/add",
+                       f"jit(step)/{outer}{s}"]
+    vocab = scopes.vocabulary()
+    for stack in stacks:
+        assert vocab.attribute(stack) == old_attribute(stack), stack
+    assert red.total_s == pytest.approx(3.2178107360000014, rel=1e-12)
+    assert red.scope_s["sfa.block_maps"] == pytest.approx(0.6772982520000008,
+                                                          rel=1e-12)
+    assert red.recomputed_s["sfa.fwd"] == pytest.approx(0.44636592400000014,
+                                                        rel=1e-12)
+
+
+def test_configuration_declares_scopes():
+    """A configuration's own scope names join the vocabulary; its forward
+    ones count as recomputed under ``rematted_computation``."""
+    vocab = scopes.Vocabulary.of([{"scopes": {
+        "names": ["moe.experts", "moe.router"], "forward": ["moe.experts"]}}])
+    assert vocab.names[-2:] == ("moe.experts", "moe.router")
+    stack = ("jit(step)/transpose(jvp())/checkpoint/rematted_computation/"
+             "mlp/moe.experts/dot_general")
+    assert vocab.attribute(stack) == ("moe.experts", True)
+    assert vocab.attribute(stack.replace("experts", "router")) == (
+        "moe.router", False)
+    assert scopes.vocabulary().attribute(stack) == ("mlp", True)
+    with pytest.raises(registry.BenchError, match="forward"):
+        scopes.Vocabulary.of([{"scopes": {"names": [], "forward": ["x"]}}])
 
 
 def test_scoped_ops_cover_the_step(red):
@@ -54,7 +113,7 @@ def test_scoped_ops_cover_the_step(red):
 
 
 def test_kernel_scopes_hold_the_kernels(step, red):
-    """The FlashSFA scopes hold the kernels that kernels.json names, and
+    """The FlashSFA scopes hold the kernels that ``ops/`` names, and
     little else: their time agrees within 1%."""
     kern = trace.reduce(step, KERNELS).op_seconds
     by_name = kern["sfa_fwd"] + kern["sfa_bwd"]
@@ -167,7 +226,7 @@ def test_op_scopes_from_protobuf():
     meta = _plane("/host:metadata", [(1, "jit_step", {1: hlo})],
                   {1: "Hlo Proto"})
     space = memoryview(_msg((1, device), (1, meta)))
-    assert scopes.op_scopes(space) == {"0": {
+    assert scopes.op_scopes(space, scopes.vocabulary()) == {"0": {
         "fusion.7": ("sfa.block_maps", True), "dot.3": ("mlp", False),
         "copy.4": (None, False)}}
 

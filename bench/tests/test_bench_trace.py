@@ -94,12 +94,10 @@ def test_recorded_trace(name):
     the busy time is the union of events, and the kernels the map names
     are found."""
     rec = json.loads((DATA / name).read_text())
-    kernels = json.loads((Path(trace.__file__).parents[1] / "kernels.json")
-                         .read_text())["operations"]
-    red = trace.reduce(rec, kernels)
+    red = trace.reduce(rec)
     assert 0 < red.busy_s <= red.window_s
     ops = rec["devices"]["0"]
     assert red.busy_s <= sum(e[2] for e in ops) / 1e9 + 1e-12
-    assert red.op_seconds, "no kernel of kernels.json in the trace"
+    assert red.op_seconds, "no kernel of ops/ in the trace"
     assert sum(red.op_seconds.values()) <= red.busy_s + 1e-9
     assert len(red.top_ops) <= 10 and len(red.idle_gaps) <= 10

@@ -1,13 +1,16 @@
 """Work counts equal the bytes of each operation's inputs and outputs at
 small shapes, and the MFU formula's parameter count equals the weights'."""
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchlib import registry, weights, work
+from benchlib import registry, train_cell, work
 
 BH, N, K, D = 2, 128, 4, 32
+OPS = registry.ops()
 
 
 def nbytes(*xs):
@@ -30,7 +33,7 @@ def test_sfa_fwd_bytes():
     kv, ki = codes(ks[1], BH, N, K, D)
     v = jax.random.normal(ks[2], (BH, N, D)).astype(jnp.bfloat16)
     out, lse = flash_sfa(qv, qi, kv, ki, v, d=D, return_residuals=True)
-    w = work.sfa_fwd(bh=BH, n=N, k=K, dv=D)
+    w = OPS["sfa_fwd"].count(bh=BH, n=N, k=K, dv=D)
     assert w.bytes == nbytes(qv, qi, kv, ki, v, out, lse)
     assert w.flops == BH * N * (N + 1) // 2 * 2 * (K + D)
 
@@ -46,30 +49,65 @@ def test_sfa_bwd_bytes():
     g = jax.random.normal(ks[3], (BH, N, D)).astype(jnp.bfloat16)
     dq, dk, dv = flash_sfa_bwd(qv, qi, kv, ki, v, o, lse, g, d=D,
                                emit="compact")
-    w = work.sfa_bwd(bh=BH, n=N, k=K, dv=D,
-                     grad_b=jnp.dtype(dq.dtype).itemsize)
+    w = OPS["sfa_bwd"].count(bh=BH, n=N, k=K, dv=D,
+                             grad_b=jnp.dtype(dq.dtype).itemsize)
     assert w.bytes == nbytes(qv, qi, kv, ki, v, o, lse, g, dq, dk, dv)
     assert dq.shape == (BH, N, K) and dv.dtype == v.dtype
 
 
+def gpt2():
+    path = registry.config_file(registry.benchmark(), "gpt2-small-sfa8")
+    return (registry.model_config(registry.load_json(path)["model"]),
+            registry.layout(path))
+
+
 @pytest.mark.parametrize("config", ["gpt2-small-sfa8"])
 def test_matmul_params_counts_the_weights(config):
-    m = registry.load_json(registry.BENCH / "configs" / f"{config}.json")
-    m = m["model"]
-    shapes = jax.tree_util.tree_flatten_with_path(
-        weights.layout(m), is_leaf=lambda x: isinstance(x, tuple))[0]
-    mats = sum(int(np.prod(s)) for path, s in shapes
-               if path[-1].key == "w" and path[0].key != "pos")
-    assert work.matmul_params(m) == mats
+    """On a dense configuration N_matmul is every matrix of the layout
+    file, the token table as the tied logits matmul, and not the position
+    table (a lookup)."""
+    path = registry.config_file(registry.benchmark(), config)
+    cfg = registry.model_config(registry.load_json(path)["model"])
+    layout = registry.layout(path)
+    mats = sum(math.prod(leaf["shape"]) for leaf in layout
+               if leaf["path"][-1] == "w" and leaf["path"][0] != "pos")
+    assert work.matmul_params(cfg, layout) == mats
 
 
 def test_gpt2_train_flops_per_token():
-    bench = registry.benchmark()
-    m = registry.load_json(registry.config_file(bench, "gpt2-small-sfa8"))
-    m = m["model"]
-    assert work.matmul_params(m) == 123_532_032
-    f = work.train_flops_per_token(m, 4096)
+    cfg, layout = gpt2()
+    assert work.matmul_params(cfg, layout) == 123_532_032
+    f = work.train_flops_per_token(cfg, layout, 4096)
     assert f == 6 * 123_532_032 + 3 * 12 * 12 * 4097 * (8 + 64)
+    assert f == 868_625_280.0
+
+
+def test_gpt2_step_work():
+    """One step at batch 4 x 4096: the counts the cell's rooflines read
+    before the operations became files of their own."""
+    cfg, _ = gpt2()
+    got = train_cell.step_work(cfg, {"batch": 4, "seq_len": 4096})
+    assert got == {"sfa_fwd": work.Work(695_954_571_264, 839_909_376),
+                   "sfa_bwd": work.Work(1_391_909_142_528, 1_519_386_624)}
+
+
+@pytest.mark.parametrize("n, window", [(9, None), (9, 4), (9, 9), (9, 20),
+                                       (128, 1), (128, 32)])
+def test_causal_pairs(n, window):
+    """A query sees itself and the keys before it, its last ``window`` at
+    most (the system's mask: key > query - window)."""
+    want = sum(1 for q in range(n) for k in range(q + 1)
+               if window is None or k > q - window)
+    assert work.causal_pairs(n, window) == want
+
+
+def test_ops_are_files():
+    """Each operation file names its kernels; the trace's kernel map is
+    theirs."""
+    assert set(OPS) >= {"sfa_fwd", "sfa_bwd", "proj_rtopk", "code_grad"}
+    assert registry.kernel_map()["sfa_fwd"] == [r"^flash_sfa\.\d+$"]
+    for op in OPS.values():
+        assert op.NAMES and callable(op.work)
 
 
 def test_roofline_takes_the_larger_bound():

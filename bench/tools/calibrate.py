@@ -39,8 +39,9 @@ def half_batch(cell, seed):
     steps = int(mix["check"]["steps"])
     b, n = int(mix["batch"]), int(mix["seq_len"])
     batches = traffic.markov_batches(seed, int(mix["batches"]), b, n,
-                                     m["vocab_size"])[:steps]
-    p0 = weights.make(seed, m)
+                                     registry.model_config(m).vocab_size
+                                     )[:steps]
+    p0 = weights.make(seed, cell.layout)
     losses, g1, p = ref.train_steps(
         p0, m, [(x["tokens"][:b // 2], x["labels"][:b // 2])
                 for x in batches], dict(mix["optimizer"]),

@@ -20,7 +20,7 @@ from pathlib import Path
 BENCH = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(BENCH))
 
-from benchlib import registry, scopes, trace  # noqa: E402
+from benchlib import scopes, trace  # noqa: E402
 
 
 def main(argv=None) -> int:
@@ -31,8 +31,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     record = scopes.load(args.trace_dir)
     red = scopes.reduce(record)
-    kernels = registry.load_json(BENCH / "kernels.json")["operations"]
-    busy = trace.reduce(record, kernels, top=args.gaps)
+    busy = trace.reduce(record, top=args.gaps)
     print(f"{'scope':16s} {'device s':>10s} {'recomputed s':>13s} "
           f"{'share %':>8s}")
     for scope, sec in sorted(red.scope_s.items(), key=lambda kv: -kv[1]):
